@@ -55,8 +55,9 @@ import json
 import sys
 
 # Manifest keys whose drift makes baseline-vs-current wall clocks and
-# metrics incomparable.  git_sha/platform are intentionally absent: the
-# baseline is by definition from an older commit and another runner.
+# metrics incomparable.  git_sha/platform/jax_backend/device_kind are
+# intentionally absent: the baseline is by definition from an older commit
+# and another runner.
 MANIFEST_WARN_KEYS = ("schema", "seed", "config_hash", "python", "numpy",
                       "jax")
 

@@ -4,8 +4,8 @@
 capacity, mode) point in Python.  These kernels evaluate the same recurrences
 for *every* GLB capacity and batch at once: entity sizes broadcast to a
 ``[batch, layer, capacity]`` grid, every branch of the pseudocode becomes a
-``where`` mask, and the per-layer sum becomes one sequential ``cumsum`` along
-the layer axis.
+``where`` mask, and the per-layer sum becomes one sequential running sum along
+the layer axis (:func:`_cumsum_layers`).
 
 Bit-compatibility with the scalar reference is a design requirement (the
 equivalence tests in ``tests/test_dse_equivalence.py`` pin it): every
@@ -173,7 +173,7 @@ def training_count_grid(
 
     # Cumulative forward+backward working set of layers 1..i (GI=I etc.).
     layer_f = I + O + W
-    cum = xp.cumsum(layer_f + layer_f, axis=-2)
+    cum = _cumsum_layers(layer_f + layer_f, xp)
     resident = cum <= glb
 
     # --- GLB action counts (lines 9-10); capacity-independent ---------------
@@ -226,7 +226,28 @@ def count_grid(sizes, caps_mb, mode: str, mem: MemoryParams | None = None, xp=np
     raise ValueError(f"unknown mode {mode!r}")
 
 
+def _cumsum_layers(x, xp):
+    """Left-to-right running sum over the layer axis (-2).
+
+    On jax it is a sequential ``lax.scan`` that adds in ``np.cumsum``'s
+    order: ``jnp.cumsum`` in float64 takes minutes to compile for a TPU
+    (181 s at 152 layers, compiling for a described v5e); the scan compiles
+    in under a second.
+    """
+    if xp is np:
+        return np.cumsum(x, axis=-2)
+    import jax
+
+    def step(total, layer):
+        total = total + layer
+        return total, total
+
+    xs = xp.moveaxis(x, -2, 0)
+    _, rest = jax.lax.scan(step, xs[0], xs[1:])
+    return xp.moveaxis(xp.concatenate([xs[:1], rest]), 0, -2)
+
+
 def _layer_sum(per_layer, xp):
     """Left-to-right sum over the layer axis (bit-identical to the scalar
     ``sum(per_layer, AccessCounts())`` fold, unlike pairwise ``xp.sum``)."""
-    return xp.cumsum(per_layer, axis=-2)[..., -1, :]
+    return _cumsum_layers(per_layer, xp)[..., -1, :]

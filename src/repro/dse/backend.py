@@ -5,7 +5,7 @@ run unchanged under ``jax.numpy``.  JAX is optional: the tier-1 container
 ships it, but a CI matrix leg (and any minimal install) runs pure NumPy, so
 every import is gated and ``backend="auto"`` quietly falls back.
 
-The JAX path runs under ``jax.experimental.enable_x64``: the access-count
+The JAX path runs under ``repro.device.x64`` (``jax.enable_x64``): the access-count
 grids subtract working-set sizes from capacities at very different
 magnitudes, and float32 there would visibly drift from the float64 scalar
 reference the equivalence tests pin at 1e-9 rtol.
@@ -41,8 +41,12 @@ def resolve_backend(backend: str = "auto") -> str:
         raise ValueError(f"unknown backend {backend!r}; expected one of {BACKENDS}")
     if backend == "auto":
         return "numpy"
-    if backend == "jax" and not HAVE_JAX:
-        raise RuntimeError("backend='jax' requested but jax is not installed")
+    if backend == "jax":
+        if not HAVE_JAX:
+            raise RuntimeError("backend='jax' requested but jax is not installed")
+        from repro.device import ensure_compile_cache
+
+        ensure_compile_cache()
     return backend
 
 
@@ -56,7 +60,7 @@ def array_namespace(backend: str):
 def x64_scope(backend: str):
     """Context manager enabling 64-bit math on the JAX path (no-op on NumPy)."""
     if backend == "jax":
-        from jax.experimental import enable_x64
+        from repro.device import x64
 
-        return enable_x64()
+        return x64()
     return contextlib.nullcontext()

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -2.3819763e38
 
@@ -177,7 +176,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -206,7 +205,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_kv, hd), jnp.float32),
             pltpu.VMEM((block_kv, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

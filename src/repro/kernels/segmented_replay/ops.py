@@ -1,37 +1,29 @@
-"""Public segmented-replay ops: device cummax + the fused replay scan.
+"""Public segmented-replay ops: device cummax + the batched replay scan.
 
 Two entry points, both returning numpy arrays bit-identical to the numpy
-reference path in ``repro.sim.engine``:
+reference path in ``repro.sim.engine`` / :mod:`.ref`:
 
-* :func:`cummax` — row-wise running max via the Pallas kernel (or
-  ``jax.lax.cummax``); what ``SimConfig(backend="pallas")`` routes the 1-D
-  replay's scan through.
-* :func:`replay_scan` — the batched sweep replay's device stage: offset
-  encode -> cummax -> decode -> finish/start/wait -> queue depth, two jitted
-  XLA programs per shape (scan + depth), no per-technology host round-trips.
+* :func:`cummax` — row-wise running max on the device, via the Pallas
+  kernel (``scan="pallas"``) or an XLA doubling scan (``scan="lax"``); what
+  ``SimConfig(backend="jax"|"pallas")`` routes the 1-D replay's scan
+  through.
+* :func:`replay_scan` — the batched sweep replay: the reference's float
+  arithmetic on the host, its two order operations (the running max and the
+  queue-depth ``searchsorted``) on the device.
 
-Only association-free operations run on-device: the running max
-(comparisons), elementwise add/sub of *inputs* (single IEEE ops),
-max/min reductions, and ``searchsorted`` (comparisons).  Two families of
-float ops are deliberately kept host-side in numpy:
-
-* Reassociating reductions — ``cumsum``, float ``sum``/``mean`` — are not
-  bitwise-stable across numpy and XLA.
-* **Multiplies that feed adds**: XLA's CPU fusion contracts
-  ``v + seg_id * big`` into an FMA (one rounding instead of two), which
-  silently changes low bits relative to numpy — and
-  ``lax.optimization_barrier`` does not stop the LLVM-level contraction.
-  The segment offsets ``seg_id * big`` / ``seg_id * big2`` are therefore
-  multiplied out host-side and passed in as arrays, so the device programs
-  contain no multiply at all.
-
-This split is what makes every backend's sweep report bit-identical (pinned
-by ``tests/test_replay_kernel.py``).
-
-The replay offsets need float64 (offsets ~1e11 ns; float32 resolution there
-is ~10 us), so everything runs under ``jax.experimental.enable_x64`` — on
-CPU natively, on real TPUs via ``interpret=True`` (auto-selected off-TPU,
-same shim as ``ssd_scan``).
+Only order operations run on the device, and only on 32-bit integers.
+Each float64 is mapped on the host to an order-preserving int64 key (the
+IEEE bits with the magnitude bits flipped for negative values), split into
+an int32 ``(hi, lo)`` pair (:func:`_to_pair`), and the device compares
+pairs lexicographically.  The result then depends neither on the device's
+float64 nor on its int64, both of which a TPU emulates: on a v5e a float64
+round-trip already changes low bits, and XLA's running max over int64
+replay keys returned wrong words.  Everything that rounds — the offset
+encode/decode, finish/start/wait, the ``big2`` span — stays in numpy,
+operand for operand as in :mod:`.ref`, which also keeps multiplies (which
+XLA may contract into FMAs) and reassociating reductions off the device.
+The keys are never ``-0.0``: every keyed value is ``x + off`` with
+``off >= +0.0``, so key order and float order agree on every tie.
 
 To bound recompiles across a sweep (event counts differ per grid point),
 ``replay_scan`` pads rows to the next power of two with a *neutral tail
@@ -43,20 +35,101 @@ to the unpadded computation (see ``_pad_neutral``).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from repro.kernels.segmented_replay.segmented_replay import cummax_2d
+from repro.kernels.segmented_replay.segmented_replay import (
+    PAIR_MIN,
+    cummax_2d,
+    lexmax,
+)
 
 DEFAULT_CHUNK = 1024
+_MAGNITUDE = np.int64(0x7FFF_FFFF_FFFF_FFFF)
 
 
 def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _to_pair(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Order-preserving int32 ``(hi, lo)`` image of float64 ``x``.
+
+    The int64 key is the IEEE bits with the magnitude bits flipped for
+    negative values (no NaN, no ``-0.0``); its high word and its low word
+    (offset so that signed order is unsigned order) compare
+    lexicographically as the floats do.
+    """
+    bits = np.ascontiguousarray(x, np.float64).view(np.int64)
+    k = bits ^ ((bits >> 63) & _MAGNITUDE)
+    return (k >> 32).astype(np.int32), ((k & 0xFFFF_FFFF) - 2**31).astype(np.int32)
+
+
+def _from_pair(hi, lo) -> np.ndarray:
+    k = (np.asarray(hi).astype(np.int64) << 32) | (
+        np.asarray(lo).astype(np.int64) + 2**31
+    )
+    return (k ^ ((k >> 63) & _MAGNITUDE)).view(np.float64)
+
+
+@jax.jit
+def _cummax_lax(hi, lo):
+    """Doubling-shift running max of ``(hi, lo)`` pairs along axis 1.
+
+    The XLA twin of the Pallas kernel.  Used instead of ``jax.lax.cummax``,
+    whose TPU lowering takes minutes to compile at a million lanes; this one
+    compiles in about two seconds.
+    """
+    n, s = hi.shape[1], 1
+    while s < n:
+        fill = jnp.full((hi.shape[0], s), PAIR_MIN, jnp.int32)
+        hi, lo = lexmax(
+            hi, lo,
+            jnp.concatenate([fill, hi[:, :-s]], axis=1),
+            jnp.concatenate([fill, lo[:, :-s]], axis=1),
+        )
+        s *= 2
+    return hi, lo
+
+
+@jax.jit
+def _searchsorted_rows(a_hi, a_lo, q_hi, q_lo):
+    """Row-wise ``searchsorted(a, q, side="left")`` over ``(hi, lo)`` pairs.
+
+    A binary search with lexicographic compares: the first index whose
+    ``a`` is not below ``q`` (``a`` sorted along each row).
+    """
+    n = a_hi.shape[1]
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        at = jnp.minimum(mid, n - 1)
+        m_hi = jnp.take_along_axis(a_hi, at, axis=1)
+        m_lo = jnp.take_along_axis(a_lo, at, axis=1)
+        below = (m_hi < q_hi) | ((m_hi == q_hi) & (m_lo < q_lo))
+        open_ = lo < hi
+        return (jnp.where(open_ & below, mid + 1, lo),
+                jnp.where(open_ & ~below, mid, hi))
+
+    bounds = (jnp.zeros(q_hi.shape, jnp.int32), jnp.full(q_hi.shape, n, jnp.int32))
+    return jax.lax.fori_loop(0, n.bit_length(), halve, bounds)[0]
+
+
+def _running_max(x, scan, chunk, interpret) -> np.ndarray:
+    """Row-wise running max of float64 ``x``, ordered on the device as pairs.
+
+    The device never orders a 64-bit value: the v5e emulates 64-bit
+    integers, and XLA's running max over int64 replay keys disagreed with
+    numpy there, while the same max over int32 pairs agreed.
+    """
+    hi, lo = (jnp.asarray(w) for w in _to_pair(x))
+    if scan == "pallas":
+        hi, lo = cummax_2d(hi, lo, chunk=chunk, interpret=interpret)
+    else:
+        hi, lo = _cummax_lax(hi, lo)
+    return _from_pair(hi, lo)
 
 
 def cummax(
@@ -69,38 +142,7 @@ def cummax(
     """Row-wise running max of a 2D array, bitwise ``np.maximum.accumulate``."""
     if interpret is None:
         interpret = _auto_interpret()
-    with enable_x64():
-        xs = jnp.asarray(np.asarray(x), jnp.float64)
-        if scan == "pallas":
-            out = cummax_2d(xs, chunk=chunk, interpret=interpret)
-        else:
-            out = jax.lax.cummax(xs, axis=1)
-        return np.asarray(out)
-
-
-@functools.partial(jax.jit, static_argnames=("scan", "chunk", "interpret"))
-def _scan_jit(v, off, s_local, svc, t_s, scan, chunk, interpret):
-    """Scan stage: add/sub of inputs + cummax + max/min reductions only."""
-    aug = v + off
-    if scan == "pallas":
-        running_max = cummax_2d(aug, chunk=chunk, interpret=interpret) - off
-    else:
-        running_max = jax.lax.cummax(aug, axis=1) - off
-    finish = s_local + running_max
-    start = finish - svc
-    wait = start - t_s
-    fmax = jnp.maximum(jnp.max(finish, axis=1), jnp.max(t_s, axis=1))
-    fmin = jnp.minimum(jnp.min(finish, axis=1), jnp.min(t_s, axis=1))
-    return finish, start, wait, fmax, fmin
-
-
-@jax.jit
-def _depth_jit(finish, off2, t_s):
-    """Depth stage: searchsorted over the offset-augmented finish times."""
-    idx = jax.vmap(
-        lambda f, q: jnp.searchsorted(f, q, side="left")
-    )(finish + off2, t_s + off2)
-    return jnp.arange(finish.shape[1], dtype=jnp.int64) - idx
+    return _running_max(x, scan, chunk, interpret)
 
 
 def _next_pow2(n: int, floor: int = 4096) -> int:
@@ -150,13 +192,11 @@ def replay_scan(
     chunk: int = DEFAULT_CHUNK,
     interpret: bool | None = None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Fused batched replay scan; bitwise-equal to ``ref.replay_scan_np``.
+    """Batched replay scan; bitwise-equal to ``ref.replay_scan_np``.
 
-    ``scan="lax"`` uses ``jax.lax.cummax``; ``scan="pallas"`` the chunked
-    Pallas kernel.  Returns numpy ``(finish, start, wait, depth)``.  The
-    segment offsets are multiplied out host-side (see module docstring) and
-    the ``big2`` span derivation happens between the two device stages on
-    the stage-1 reductions — the ``(R, n)`` arrays stay on device.
+    ``scan="lax"`` runs the running max as an XLA program, ``scan="pallas"``
+    through the chunked Pallas kernel; the depth ``searchsorted`` is XLA
+    either way.  Returns numpy ``(finish, start, wait, depth)``.
     """
     if interpret is None:
         interpret = _auto_interpret()
@@ -170,22 +210,16 @@ def replay_scan(
             v, seg_id, s_local, svc, t_s, npad
         )
     off = seg_id * big[:, None]
-    with enable_x64():
-        t_dev = jnp.asarray(t_s, jnp.float64)
-        finish, start, wait, fmax, fmin = _scan_jit(
-            jnp.asarray(v, jnp.float64),
-            jnp.asarray(off, jnp.float64),
-            jnp.asarray(s_local, jnp.float64),
-            jnp.asarray(svc, jnp.float64),
-            t_dev,
-            scan=scan,
-            chunk=chunk,
-            interpret=interpret,
-        )
-        big2 = (np.asarray(fmax) - np.asarray(fmin)) + 1.0
-        off2 = seg_id * big2[:, None]
-        depth = _depth_jit(finish, jnp.asarray(off2, jnp.float64), t_dev)
-        out = tuple(
-            np.asarray(a)[:, :n] for a in (finish, start, wait, depth)
-        )
-    return out
+    running_max = _running_max(v + off, scan, chunk, interpret) - off
+    finish = s_local + running_max
+    start = finish - svc
+    wait = start - t_s
+    fmax = np.maximum(finish.max(axis=1), t_s.max(axis=1))
+    fmin = np.minimum(finish.min(axis=1), t_s.min(axis=1))
+    big2 = (fmax - fmin) + 1.0
+    off2 = seg_id * big2[:, None]
+    idx = _searchsorted_rows(
+        *(jnp.asarray(w) for w in (*_to_pair(finish + off2), *_to_pair(t_s + off2)))
+    )
+    depth = np.arange(npad) - np.asarray(idx).astype(np.int64)
+    return finish[:, :n], start[:, :n], wait[:, :n], depth[:, :n]

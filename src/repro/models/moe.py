@@ -166,10 +166,7 @@ def apply_moe_shard_map(p, x: jax.Array, cfg: ModelConfig, mesh, data_axes):
     (partial-sum down-projection + reduce-scatter epilogue)."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.shard_map import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax.shard_map import shard_map
 
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.top_k

@@ -1,8 +1,9 @@
 """Run manifests: provenance stamped into every JSON artifact.
 
 A manifest answers "what produced this file?": git sha, RNG seed, a stable
-hash of the driving config/Scenario, library versions, platform, and the
-wall-time-per-phase split recorded by :mod:`repro.obs.core`.  Two runs
+hash of the driving config/Scenario, library versions, platform, the JAX
+backend and device kind, and the wall-time-per-phase split recorded by
+:mod:`repro.obs.core`.  Two runs
 whose manifests agree on ``(git_sha, seed, config_hash, versions)`` should
 produce bit-identical metrics; ``manifest_diff`` makes disagreement
 legible, and ``benchmarks/check_bench.py`` warns when a fresh benchmark
@@ -80,20 +81,24 @@ def config_hash(config) -> str:
 
 
 def environment() -> dict:
-    """Library versions + platform (the reproducibility-relevant subset)."""
+    """Library versions, platform, and the JAX device the run sees."""
     import numpy as np
 
     try:
         import jax
 
-        jax_version = jax.__version__
-    except Exception:
-        jax_version = None
+        from repro.device import device_info
+
+        jax_version, device = jax.__version__, device_info()
+    except (ImportError, RuntimeError):
+        jax_version, device = None, {}
     return {
         "python": platform.python_version(),
         "numpy": np.__version__,
         "jax": jax_version,
         "platform": platform.platform(),
+        "jax_backend": device.get("platform"),
+        "device_kind": device.get("kind"),
     }
 
 
@@ -140,7 +145,7 @@ def stamp(payload: dict, seed: int | None = None, config=None,
 # Fields whose disagreement makes two runs incomparable; everything else
 # (timestamps, phase timings) is expected to vary run to run.
 COMPARABLE_KEYS = ("schema", "git_sha", "seed", "config_hash", "python",
-                   "numpy", "jax", "platform")
+                   "numpy", "jax", "platform", "jax_backend", "device_kind")
 
 
 def manifest_diff(a: dict | None, b: dict | None,

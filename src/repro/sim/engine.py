@@ -89,9 +89,13 @@ def resolve_backend(backend: str) -> str:
             import jax
         except ImportError:
             return "numpy"
-        return "jax" if jax.default_backend() != "cpu" else "numpy"
+        backend = "jax" if jax.default_backend() != "cpu" else "numpy"
     if backend not in BACKENDS:
         raise UnknownBackendError(backend)
+    if backend != "numpy":
+        from repro.device import ensure_compile_cache
+
+        ensure_compile_cache()
     return backend
 
 
@@ -154,19 +158,12 @@ class SimResult:
 
 
 def _cummax(x: np.ndarray, backend: str) -> np.ndarray:
-    if backend == "jax":
-        import jax
-        from jax.experimental import enable_x64
+    if backend == "numpy":
+        return np.maximum.accumulate(x)
+    from repro.kernels.segmented_replay.ops import cummax
 
-        # The segment-offset trick needs float64: offsets reach ~1e11 ns and
-        # float32 resolution there is ~10 us.
-        with enable_x64():
-            return np.asarray(jax.lax.cummax(jax.numpy.asarray(x, jax.numpy.float64)))
-    if backend == "pallas":
-        from repro.kernels.segmented_replay.ops import cummax
-
-        return cummax(np.asarray(x)[None], scan="pallas")[0]
-    return np.maximum.accumulate(x)
+    scan = "pallas" if backend == "pallas" else "lax"
+    return cummax(np.asarray(x)[None], scan=scan)[0]
 
 
 def coalesce_dropped_indices(

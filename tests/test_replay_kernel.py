@@ -217,7 +217,7 @@ def test_float_times_bitwise(backend):
 
 
 @needs_jax
-@pytest.mark.parametrize("chunk", [64, 256, 1024])
+@pytest.mark.parametrize("chunk", [128, 256, 1024])
 def test_cummax_matches_numpy(chunk):
     """Device cummax == ``np.maximum.accumulate`` bitwise, across chunkings."""
     from repro.kernels.segmented_replay.ops import cummax
