@@ -31,6 +31,16 @@ segment*: pad values chosen so the padded entries form their own trailing
 segment whose finish/issue values stay inside the real data's range — the
 big2 span, every real output, and every real queue depth are bit-identical
 to the unpadded computation (see ``_pad_neutral``).
+
+With ``repro.obs`` enabled, each host phase opens a span (``pad``,
+``encode``, ``decode``) and each device program one ``device/cummax`` or
+``device/search`` span, from the first transfer of its int32 pairs to the
+numpy result in hand (transfer in, kernel, transfer out); ``replay_scan``
+counts the lanes it gives the device (``replay/lanes``, R x n) and the
+padded lanes they fill (``replay/lanes_padded``, R x npad).  The device
+programs carry stable names (``replay_cummax``, ``replay_search``) in the
+profiler's trace: as module names (``jit_replay_cummax``,
+``jit_replay_search``) and as a ``jax.named_scope`` over their ops.
 """
 
 from __future__ import annotations
@@ -44,6 +54,7 @@ from repro.kernels.segmented_replay.segmented_replay import (
     cummax_2d,
     lexmax,
 )
+from repro.obs import core as obs
 
 DEFAULT_CHUNK = 1024
 _MAGNITUDE = np.int64(0x7FFF_FFFF_FFFF_FFFF)
@@ -51,6 +62,21 @@ _MAGNITUDE = np.int64(0x7FFF_FFFF_FFFF_FFFF)
 
 def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _named_jit(name: str):
+    """``jax.jit`` under a stable program name.
+
+    The compiled module is ``jit_<name>``, the name a profiler trace gives
+    the module's event around the program's ops, whatever the Python
+    function is called; a ``jax.named_scope`` of the same name inside marks
+    each op's metadata as well.
+    """
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+
+    return wrap
 
 
 def _to_pair(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -73,7 +99,7 @@ def _from_pair(hi, lo) -> np.ndarray:
     return (k ^ ((k >> 63) & _MAGNITUDE)).view(np.float64)
 
 
-@jax.jit
+@_named_jit("replay_cummax")
 def _cummax_lax(hi, lo):
     """Doubling-shift running max of ``(hi, lo)`` pairs along axis 1.
 
@@ -82,18 +108,19 @@ def _cummax_lax(hi, lo):
     compiles in about two seconds.
     """
     n, s = hi.shape[1], 1
-    while s < n:
-        fill = jnp.full((hi.shape[0], s), PAIR_MIN, jnp.int32)
-        hi, lo = lexmax(
-            hi, lo,
-            jnp.concatenate([fill, hi[:, :-s]], axis=1),
-            jnp.concatenate([fill, lo[:, :-s]], axis=1),
-        )
-        s *= 2
+    with jax.named_scope("replay_cummax"):
+        while s < n:
+            fill = jnp.full((hi.shape[0], s), PAIR_MIN, jnp.int32)
+            hi, lo = lexmax(
+                hi, lo,
+                jnp.concatenate([fill, hi[:, :-s]], axis=1),
+                jnp.concatenate([fill, lo[:, :-s]], axis=1),
+            )
+            s *= 2
     return hi, lo
 
 
-@jax.jit
+@_named_jit("replay_search")
 def _searchsorted_rows(a_hi, a_lo, q_hi, q_lo):
     """Row-wise ``searchsorted(a, q, side="left")`` over ``(hi, lo)`` pairs.
 
@@ -113,23 +140,34 @@ def _searchsorted_rows(a_hi, a_lo, q_hi, q_lo):
         return (jnp.where(open_ & below, mid + 1, lo),
                 jnp.where(open_ & ~below, mid, hi))
 
-    bounds = (jnp.zeros(q_hi.shape, jnp.int32), jnp.full(q_hi.shape, n, jnp.int32))
-    return jax.lax.fori_loop(0, n.bit_length(), halve, bounds)[0]
+    with jax.named_scope("replay_search"):
+        bounds = (jnp.zeros(q_hi.shape, jnp.int32),
+                  jnp.full(q_hi.shape, n, jnp.int32))
+        return jax.lax.fori_loop(0, n.bit_length(), halve, bounds)[0]
 
 
-def _running_max(x, scan, chunk, interpret) -> np.ndarray:
-    """Row-wise running max of float64 ``x``, ordered on the device as pairs.
+def _device_cummax(hi, lo, scan, chunk, interpret):
+    """Row-wise running max of int32 ``(hi, lo)`` pairs on the device.
 
     The device never orders a 64-bit value: the v5e emulates 64-bit
     integers, and XLA's running max over int64 replay keys disagreed with
-    numpy there, while the same max over int32 pairs agreed.
+    numpy there, while the same max over int32 pairs agreed.  Blocks until
+    the numpy result is on the host.
     """
-    hi, lo = (jnp.asarray(w) for w in _to_pair(x))
-    if scan == "pallas":
-        hi, lo = cummax_2d(hi, lo, chunk=chunk, interpret=interpret)
-    else:
-        hi, lo = _cummax_lax(hi, lo)
-    return _from_pair(hi, lo)
+    with obs.span("device/cummax"):
+        hi, lo = jnp.asarray(hi), jnp.asarray(lo)
+        if scan == "pallas":
+            hi, lo = cummax_2d(hi, lo, chunk=chunk, interpret=interpret)
+        else:
+            hi, lo = _cummax_lax(hi, lo)
+        return np.asarray(hi), np.asarray(lo)
+
+
+def _device_search(a_hi, a_lo, q_hi, q_lo) -> np.ndarray:
+    """:func:`_searchsorted_rows` on the device; blocks for the numpy result."""
+    with obs.span("device/search"):
+        pairs = [jnp.asarray(w) for w in (a_hi, a_lo, q_hi, q_lo)]
+        return np.asarray(_searchsorted_rows(*pairs))
 
 
 def cummax(
@@ -142,7 +180,11 @@ def cummax(
     """Row-wise running max of a 2D array, bitwise ``np.maximum.accumulate``."""
     if interpret is None:
         interpret = _auto_interpret()
-    return _running_max(x, scan, chunk, interpret)
+    with obs.span("encode"):
+        hi, lo = _to_pair(x)
+    hi, lo = _device_cummax(hi, lo, scan, chunk, interpret)
+    with obs.span("decode"):
+        return _from_pair(hi, lo)
 
 
 def _next_pow2(n: int, floor: int = 4096) -> int:
@@ -205,21 +247,29 @@ def replay_scan(
         e = np.empty((R, 0))
         return e, e.copy(), e.copy(), np.empty((R, 0), np.int64)
     npad = _next_pow2(n)
+    obs.count("replay/lanes", R * n)
+    obs.count("replay/lanes_padded", R * npad)
     if npad != n:
-        v, seg_id, s_local, svc, t_s = _pad_neutral(
-            v, seg_id, s_local, svc, t_s, npad
-        )
-    off = seg_id * big[:, None]
-    running_max = _running_max(v + off, scan, chunk, interpret) - off
-    finish = s_local + running_max
-    start = finish - svc
-    wait = start - t_s
-    fmax = np.maximum(finish.max(axis=1), t_s.max(axis=1))
-    fmin = np.minimum(finish.min(axis=1), t_s.min(axis=1))
-    big2 = (fmax - fmin) + 1.0
-    off2 = seg_id * big2[:, None]
-    idx = _searchsorted_rows(
-        *(jnp.asarray(w) for w in (*_to_pair(finish + off2), *_to_pair(t_s + off2)))
-    )
-    depth = np.arange(npad) - np.asarray(idx).astype(np.int64)
-    return finish[:, :n], start[:, :n], wait[:, :n], depth[:, :n]
+        with obs.span("pad"):
+            v, seg_id, s_local, svc, t_s = _pad_neutral(
+                v, seg_id, s_local, svc, t_s, npad
+            )
+    with obs.span("encode"):
+        off = seg_id * big[:, None]
+        hi, lo = _to_pair(v + off)
+    hi, lo = _device_cummax(hi, lo, scan, chunk, interpret)
+    with obs.span("decode"):
+        running_max = _from_pair(hi, lo) - off
+        finish = s_local + running_max
+        start = finish - svc
+        wait = start - t_s
+        fmax = np.maximum(finish.max(axis=1), t_s.max(axis=1))
+        fmin = np.minimum(finish.min(axis=1), t_s.min(axis=1))
+        big2 = (fmax - fmin) + 1.0
+    with obs.span("encode"):
+        off2 = seg_id * big2[:, None]
+        pairs = (*_to_pair(finish + off2), *_to_pair(t_s + off2))
+    idx = _device_search(*pairs)
+    with obs.span("decode"):
+        depth = np.arange(npad) - idx.astype(np.int64)
+        return finish[:, :n], start[:, :n], wait[:, :n], depth[:, :n]

@@ -103,5 +103,6 @@ def cummax_2d(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="replay_cummax",
     )(hi, lo)
     return out_hi[:R, :n], out_lo[:R, :n]

@@ -17,6 +17,13 @@ run manifest embeds as ``phases_s`` so every JSON artifact says where its
 wall time went.  State is process-global and single-threaded by design
 (the engines are single-threaded array programs); ``reset()`` clears it
 between runs.
+
+``enable(annotate=...)`` shares the spans with another clock: given a
+context-manager factory such as ``jax.profiler.TraceAnnotation``, every
+enabled span also enters ``annotate(path)`` under its full slash path, so
+the span lands on that tool's timeline too (a profiler trace, aligned with
+the device's operations).  This module itself imports nothing beyond the
+standard library.
 """
 
 from __future__ import annotations
@@ -41,29 +48,36 @@ _STATE: "_ObsState | None" = None  # None <=> disabled
 
 
 class _ObsState:
-    __slots__ = ("spans", "counters", "stack")
+    __slots__ = ("spans", "counters", "stack", "annotate")
 
-    def __init__(self):
+    def __init__(self, annotate=None):
         self.spans: dict[str, list] = {}  # path -> [n_calls, total_s]
         self.counters: dict[str, float] = {}
         self.stack: list[str] = []
+        self.annotate = annotate  # path -> context manager, or None
 
 
 class _Span:
-    __slots__ = ("name", "t0")
+    __slots__ = ("name", "t0", "mark")
 
     def __init__(self, name: str):
         self.name = name
+        self.mark = None
 
     def __enter__(self):
         state = _STATE
         if state is not None:  # disabled mid-flight: degrade to no-op
             state.stack.append(self.name)
+            if state.annotate is not None:
+                self.mark = state.annotate("/".join(state.stack))
+                self.mark.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
+        if self.mark is not None:
+            self.mark.__exit__(*exc)
         state = _STATE
         if state is not None and state.stack:
             path = "/".join(state.stack)
@@ -77,11 +91,17 @@ class _Span:
         return False
 
 
-def enable() -> None:
-    """Turn recording on (fresh state).  Idempotent."""
+def enable(annotate=None) -> None:
+    """Turn recording on (fresh state).  Idempotent.
+
+    ``annotate`` (a factory ``path -> context manager``, e.g.
+    ``jax.profiler.TraceAnnotation``) is entered around every span under
+    its full path from now on; ``None`` stops annotating.
+    """
     global _STATE
     if _STATE is None:
         _STATE = _ObsState()
+    _STATE.annotate = annotate
 
 
 def disable() -> None:
@@ -98,7 +118,7 @@ def reset() -> None:
     """Clear spans/counters without changing the enabled/disabled state."""
     global _STATE
     if _STATE is not None:
-        _STATE = _ObsState()
+        _STATE = _ObsState(_STATE.annotate)
 
 
 def span(name: str):

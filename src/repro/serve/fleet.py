@@ -61,6 +61,7 @@ import numpy as np
 from repro.core.memory_system import HybridMemorySystem
 from repro.core.workload import NLPModelSpec
 from repro.faults import FaultConfig, derate_system, replica_fail_times_ns
+from repro.obs import core as obs
 from repro.sim.engine import SimConfig
 from repro.sim.trace import ServingConfig, Trace, draw_requests
 from repro.serve.lower import (
@@ -845,88 +846,96 @@ def fleet_serving(
     requeued onto survivors after a capped exponential backoff, their lost
     KV re-prefilled, while the router excludes them and the autoscaler (if
     on) brings replacements up.  ``faults=None`` is bit-identical to today.
+
+    ``timing`` and the :mod:`repro.obs` spans ``loop`` and ``score`` split
+    the wall time as in ``closed_loop_serving``.
     """
     t_loop0 = time.perf_counter()
-    base_system = system
-    if faults is not None:
-        faults.validate()
-        system = derate_system(system, faults)
-    rng = np.random.default_rng(cfg.seed)
-    arrivals, prompts, decodes = draw_requests(cfg, rng)
+    with obs.span("loop"):
+        base_system = system
+        if faults is not None:
+            faults.validate()
+            system = derate_system(system, faults)
+        rng = np.random.default_rng(cfg.seed)
+        arrivals, prompts, decodes = draw_requests(cfg, rng)
 
-    fleet = Fleet(system, spec, cfg, engine_cfg, fleet_cfg,
-                  lowering=lowering, recorder=recorder, faults=faults)
-    # The pricer only reads run-level constants off the model (the KV-append
-    # line namespace); replica 0's own model is built by run().
-    seed_model = ServeModel(system, spec, cfg, engine_cfg)
-    pricer = TechPricer(system, seed_model, n_dram_channels,
-                        n_prefetch_channels, n_replicas=fleet.capacity,
-                        faults=faults)
+        fleet = Fleet(system, spec, cfg, engine_cfg, fleet_cfg,
+                      lowering=lowering, recorder=recorder, faults=faults)
+        # The pricer only reads run-level constants off the model (the
+        # KV-append line namespace); replica 0's own model is built by run().
+        seed_model = ServeModel(system, spec, cfg, engine_cfg)
+        pricer = TechPricer(system, seed_model, n_dram_channels,
+                            n_prefetch_channels, n_replicas=fleet.capacity,
+                            faults=faults)
 
-    def step_time(replica: _Replica, blocks: StepBlocks) -> float:
-        glb_ns, dram_ns = pricer.price_step(blocks)
-        decode_ns = replica.model.interval_ns if blocks.has_decode else 0.0
-        return max(decode_ns, blocks.prefill_ns, glb_ns, dram_ns)
+        def step_time(replica: _Replica, blocks: StepBlocks) -> float:
+            glb_ns, dram_ns = pricer.price_step(blocks)
+            decode_ns = (replica.model.interval_ns if blocks.has_decode
+                         else 0.0)
+            return max(decode_ns, blocks.prefill_ns, glb_ns, dram_ns)
 
-    def price_block(blocks: StepBlocks) -> None:
-        pricer.price_step(blocks)  # transfer events: priced, never pacing
+        def price_block(blocks: StepBlocks) -> None:
+            pricer.price_step(blocks)  # transfer events: priced, never pacing
 
-    fleet.run(arrivals, prompts, decodes, step_time, price_block=price_block)
+        fleet.run(arrivals, prompts, decodes, step_time,
+                  price_block=price_block)
     t_score0 = time.perf_counter()
-
-    model0 = fleet.replicas[0].model
-    # A trivial (1-replica, knobs-off) fleet keeps the closed loop's exact
-    # metadata so the whole trace stays bit-identical.
-    extra = {} if fleet_cfg.trivial else fleet.fleet_meta()
-    if faults is not None:
-        extra["faults"] = faults.to_dict()
-        if pricer.fm is not None:
-            extra["fault_stats"] = pricer.fm.stats()
-        if faults.has_replica_faults:
-            extra.update(fleet.fault_meta())
-    trace = pricer.b.build(
-        compute_time_s=0.0,
-        meta=serving_run_meta(spec, cfg, engine_cfg, system, model0,
-                              fleet.stats, lowering, **extra),
-    )
-    mean_alive = fleet.mean_alive()
-    if mean_alive != 1.0:
-        # A fleet leaks on every alive chip; the 1-replica path skips the
-        # multiply so its leakage term stays bit-identical to the closed
-        # loop's.
-        trace.leakage_w = system.glb.leakage_w * mean_alive
-    sim_config = sim_config or SimConfig(
-        coalesce_window_ns=4 * model0.interval_ns, kind_stats=False
-    )
-    report = score_requests(
-        trace,
-        requests=fleet.logical,
-        finished=fleet.finished_logical,
-        offered_qps=cfg.arrival_rate_rps,
-        pages_spilled=fleet.pages_spilled(),
-        pages_allocated=fleet.pages_allocated(),
-        stats=fleet.stats,
-        system=system,
-        sim_config=sim_config,
-        arrival_by_rid=fleet.arrival_by_rid,
-        recorder=recorder,
-    )
-    fr = fleet.finalize(
-        report, system,
-        fault_stats=pricer.fm.stats() if (faults is not None
-                                          and pricer.fm is not None) else None,
-    )
-    if faults is not None and faults.baseline_inflation:
-        # One fault-free rerun anchors the degradation metric: how much the
-        # campaign inflated the tail TTFT over the same offered load.
-        _, base = fleet_serving(
-            base_system, spec, cfg, engine_cfg, fleet_cfg, sim_config,
-            n_dram_channels, n_prefetch_channels, lowering,
-        )
-        if base.report.ttft_p99_ms > 0:
-            fr.ttft_p99_inflation = (
-                fr.report.ttft_p99_ms / base.report.ttft_p99_ms
+    with obs.span("score"):
+        model0 = fleet.replicas[0].model
+        # A trivial (1-replica, knobs-off) fleet keeps the closed loop's
+        # exact metadata so the whole trace stays bit-identical.
+        extra = {} if fleet_cfg.trivial else fleet.fleet_meta()
+        if faults is not None:
+            extra["faults"] = faults.to_dict()
+            if pricer.fm is not None:
+                extra["fault_stats"] = pricer.fm.stats()
+            if faults.has_replica_faults:
+                extra.update(fleet.fault_meta())
+        with obs.span("trace"):
+            trace = pricer.b.build(
+                compute_time_s=0.0,
+                meta=serving_run_meta(spec, cfg, engine_cfg, system, model0,
+                                      fleet.stats, lowering, **extra),
             )
+        mean_alive = fleet.mean_alive()
+        if mean_alive != 1.0:
+            # A fleet leaks on every alive chip; the 1-replica path skips
+            # the multiply so its leakage term stays bit-identical to the
+            # closed loop's.
+            trace.leakage_w = system.glb.leakage_w * mean_alive
+        sim_config = sim_config or SimConfig(
+            coalesce_window_ns=4 * model0.interval_ns, kind_stats=False
+        )
+        report = score_requests(
+            trace,
+            requests=fleet.logical,
+            finished=fleet.finished_logical,
+            offered_qps=cfg.arrival_rate_rps,
+            pages_spilled=fleet.pages_spilled(),
+            pages_allocated=fleet.pages_allocated(),
+            stats=fleet.stats,
+            system=system,
+            sim_config=sim_config,
+            arrival_by_rid=fleet.arrival_by_rid,
+            recorder=recorder,
+        )
+        fr = fleet.finalize(
+            report, system,
+            fault_stats=(pricer.fm.stats() if (faults is not None
+                                               and pricer.fm is not None)
+                         else None),
+        )
+        if faults is not None and faults.baseline_inflation:
+            # One fault-free rerun anchors the degradation metric: how much
+            # the campaign inflated the tail TTFT over the same offered load.
+            _, base = fleet_serving(
+                base_system, spec, cfg, engine_cfg, fleet_cfg, sim_config,
+                n_dram_channels, n_prefetch_channels, lowering,
+            )
+            if base.report.ttft_p99_ms > 0:
+                fr.ttft_p99_inflation = (
+                    fr.report.ttft_p99_ms / base.report.ttft_p99_ms
+                )
     if timing is not None:
         timing["loop_s"] = timing.get("loop_s", 0.0) + (t_score0 - t_loop0)
         timing["score_s"] = (

@@ -53,6 +53,7 @@ from repro.core.access_counts import MemoryParams
 from repro.core.memory_system import HybridMemorySystem
 from repro.core.workload import NLPModelSpec
 from repro.faults import FaultConfig, derate_system, fault_model_for
+from repro.obs import core as obs
 from repro.sim.engine import SimConfig, SimResult, simulate_trace
 from repro.sim.trace import (
     KIND_DRAM_RD,
@@ -824,7 +825,9 @@ def closed_loop_serving(
     reference loop — bit-identical output, kept for equivalence testing and
     the ``benchmarks/serving_qps`` speedup baseline).  Pass a dict as
     ``timing`` to receive the ``loop_s`` (scheduler + allocator + lowering +
-    pricing) vs ``score_s`` (trace build + replay + report) wall-clock split.
+    pricing) vs ``score_s`` (trace build + replay + report) wall-clock split;
+    with :mod:`repro.obs` enabled the same two phases are the spans ``loop``
+    and ``score`` (``score/trace``, then the replay's own spans).
     ``recorder`` (a :class:`repro.obs.TimelineRecorder`) taps the loop's
     request lifecycles/counters and the replay's bank timeline for Perfetto
     export; all recorder hooks are read-only, so the returned trace and
@@ -836,48 +839,51 @@ def closed_loop_serving(
     default ``None`` leaves the run bit-identical to a fault-free build.
     """
     t_loop0 = time.perf_counter()
-    if faults is not None:
-        faults.validate()
-        system = derate_system(system, faults)
-    rng = np.random.default_rng(cfg.seed)
-    arrivals, prompts, decodes = draw_requests(cfg, rng)
-    sched = ContinuousBatchScheduler(arrivals, prompts, decodes, engine_cfg)
-    model = ServeModel(system, spec, cfg, engine_cfg)
-    if lowering == "block":
-        emitter = BlockEmitter(model)
-    elif lowering == "scalar":
-        emitter = ScalarEmitter(model)
-    else:
-        raise ValueError(f"unknown lowering {lowering!r}")
-    pricer = TechPricer(system, model, n_dram_channels, n_prefetch_channels,
-                        faults=faults)
-    stats = RunStats()
+    with obs.span("loop"):
+        if faults is not None:
+            faults.validate()
+            system = derate_system(system, faults)
+        rng = np.random.default_rng(cfg.seed)
+        arrivals, prompts, decodes = draw_requests(cfg, rng)
+        sched = ContinuousBatchScheduler(arrivals, prompts, decodes,
+                                         engine_cfg)
+        model = ServeModel(system, spec, cfg, engine_cfg)
+        if lowering == "block":
+            emitter = BlockEmitter(model)
+        elif lowering == "scalar":
+            emitter = ScalarEmitter(model)
+        else:
+            raise ValueError(f"unknown lowering {lowering!r}")
+        pricer = TechPricer(system, model, n_dram_channels,
+                            n_prefetch_channels, faults=faults)
+        stats = RunStats()
 
-    def step_time(blocks: StepBlocks) -> float:
-        glb_ns, dram_ns = pricer.price_step(blocks)
-        decode_ns = model.interval_ns if blocks.has_decode else 0.0
-        return max(decode_ns, blocks.prefill_ns, glb_ns, dram_ns)
+        def step_time(blocks: StepBlocks) -> float:
+            glb_ns, dram_ns = pricer.price_step(blocks)
+            decode_ns = model.interval_ns if blocks.has_decode else 0.0
+            return max(decode_ns, blocks.prefill_ns, glb_ns, dram_ns)
 
-    for blocks, dt in drive_serving_loop(sched, emitter, step_time, model.alloc,
-                                         recorder=recorder):
-        stats.account(blocks, dt)
+        for blocks, dt in drive_serving_loop(sched, emitter, step_time,
+                                             model.alloc, recorder=recorder):
+            stats.account(blocks, dt)
     t_score0 = time.perf_counter()
-
-    fault_extra = {}
-    if faults is not None:
-        fault_extra = {"faults": faults.to_dict()}
-        if pricer.fm is not None:
-            fault_extra["fault_stats"] = pricer.fm.stats()
-    trace = pricer.b.build(
-        compute_time_s=0.0,
-        meta=serving_run_meta(spec, cfg, engine_cfg, system, model, stats,
-                              lowering, **fault_extra),
-    )
-    sim_config = sim_config or SimConfig(
-        coalesce_window_ns=4 * model.interval_ns, kind_stats=False
-    )
-    report = score_run(trace, sched, model, stats, system, sim_config,
-                       recorder=recorder)
+    with obs.span("score"):
+        fault_extra = {}
+        if faults is not None:
+            fault_extra = {"faults": faults.to_dict()}
+            if pricer.fm is not None:
+                fault_extra["fault_stats"] = pricer.fm.stats()
+        with obs.span("trace"):
+            trace = pricer.b.build(
+                compute_time_s=0.0,
+                meta=serving_run_meta(spec, cfg, engine_cfg, system, model,
+                                      stats, lowering, **fault_extra),
+            )
+        sim_config = sim_config or SimConfig(
+            coalesce_window_ns=4 * model.interval_ns, kind_stats=False
+        )
+        report = score_run(trace, sched, model, stats, system, sim_config,
+                           recorder=recorder)
     if timing is not None:
         timing["loop_s"] = timing.get("loop_s", 0.0) + (t_score0 - t_loop0)
         timing["score_s"] = (

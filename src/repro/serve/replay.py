@@ -43,6 +43,7 @@ import dataclasses
 import numpy as np
 
 from repro.core.memory_system import HybridMemorySystem
+from repro.obs import core as obs
 from repro.sim.engine import (
     _EXPOSED_LUT,
     BatchedReplaySchedule,
@@ -474,92 +475,94 @@ def score_shared_batch(
             for i, (tr, system) in enumerate(zip(traces, systems))
         ]
 
-    dropped = np.empty(0, np.int64)
-    kept = np.arange(n_total, dtype=np.int64)
-    if sim_config.coalesce_window_ns > 0:
-        dropped = coalesce_dropped_indices(
-            t0.t_issue_ns, t0.kind, t0.line, sim_config.coalesce_window_ns
-        )
-        keep = np.ones(n_total, bool)
-        keep[dropped] = False
-        kept = np.flatnonzero(keep)
+    with obs.span("coalesce"):
+        dropped = np.empty(0, np.int64)
+        kept = np.arange(n_total, dtype=np.int64)
+        if sim_config.coalesce_window_ns > 0:
+            dropped = coalesce_dropped_indices(
+                t0.t_issue_ns, t0.kind, t0.line, sim_config.coalesce_window_ns
+            )
+            keep = np.ones(n_total, bool)
+            keep[dropped] = False
+            kept = np.flatnonzero(keep)
 
-    t_k = t0.t_issue_ns[kept]
-    kind_k = t0.kind[kept]
-    res_k = np.stack([tr.resource[kept] for tr in traces])
-    svc_k = np.stack([tr.service_ns[kept] for tr in traces])
+        t_k = t0.t_issue_ns[kept]
+        kind_k = t0.kind[kept]
+        res_k = np.stack([tr.resource[kept] for tr in traces])
+        svc_k = np.stack([tr.service_ns[kept] for tr in traces])
     batch = replay_schedule_batch(t_k, res_k, svc_k, kind_k,
                                   backend=sim_config.backend)
     if recorder is not None:
         recorder.record_replay(batch.row(0), t0)
 
-    # Scheduler-clock metrics are shared by every technology on the grid.
-    if arrival_by_rid is None:
-        arrival_by_rid = {req.rid: req.arrival_ns for req in finished}
-    sched_ttft = np.array(
-        [req.first_token_ns - arrival_by_rid.get(req.rid, req.arrival_ns)
-         for req in finished]
-    )
-    sched_tpot = np.array(
-        [
-            (req.finish_ns - req.first_token_ns) / (req.decoded - 1)
-            for req in finished
-            if req.decoded > 1
-        ]
-    )
-    finishes = [req.finish_ns for req in finished]
-    arrivals = [arrival_by_rid.get(req.rid, req.arrival_ns)
-                for req in requests]
-    span_ns = (max(finishes) - min(arrivals)) if finishes else 0.0
-    kv_rd_total = stats.kv_rd_bytes_glb + stats.kv_rd_bytes_dram
+    with obs.span("distill"):
+        # Scheduler-clock metrics are shared by every technology on the grid.
+        if arrival_by_rid is None:
+            arrival_by_rid = {req.rid: req.arrival_ns for req in finished}
+        sched_ttft = np.array(
+            [req.first_token_ns - arrival_by_rid.get(req.rid, req.arrival_ns)
+             for req in finished]
+        )
+        sched_tpot = np.array(
+            [
+                (req.finish_ns - req.first_token_ns) / (req.decoded - 1)
+                for req in finished
+                if req.decoded > 1
+            ]
+        )
+        finishes = [req.finish_ns for req in finished]
+        arrivals = [arrival_by_rid.get(req.rid, req.arrival_ns)
+                    for req in requests]
+        span_ns = (max(finishes) - min(arrivals)) if finishes else 0.0
+        kv_rd_total = stats.kv_rd_bytes_glb + stats.kv_rd_bytes_dram
 
-    reports = []
-    for r, (trace, system) in enumerate(zip(traces, systems)):
-        energy_k = trace.energy_pj[kept]
-        coalesced_e = float(trace.energy_pj[dropped].sum())
-        result = _distill_row(batch, r, trace, kind_k, energy_k, n_total,
-                              int(dropped.size), coalesced_e, sim_config)
+        reports = []
+        for r, (trace, system) in enumerate(zip(traces, systems)):
+            energy_k = trace.energy_pj[kept]
+            coalesced_e = float(trace.energy_pj[dropped].sum())
+            result = _distill_row(batch, r, trace, kind_k, energy_k, n_total,
+                                  int(dropped.size), coalesced_e, sim_config)
 
-        # Per-request token completions from the replay's tagged events,
-        # exactly as in ``score_run``.
-        orig_idx = kept[batch.order[r]]
-        ttft, tpot = replay_token_times(trace.tag[orig_idx],
-                                        batch.finish_ns[r], arrival_by_rid)
+            # Per-request token completions from the replay's tagged events,
+            # exactly as in ``score_run``.
+            orig_idx = kept[batch.order[r]]
+            ttft, tpot = replay_token_times(trace.tag[orig_idx],
+                                            batch.finish_ns[r], arrival_by_rid)
 
-        ttft_p50, ttft_p99 = _percentiles_ms(ttft)
-        tpot_p50, tpot_p99 = _percentiles_ms(tpot)
-        reports.append(ServeReport(
-            n_requests=len(requests),
-            completed=len(finished),
-            n_steps=stats.n_steps,
-            offered_qps=offered_qps,
-            achieved_qps=(
-                len(finished) / (span_ns * 1e-9) if span_ns else 0.0
-            ),
-            span_s=span_ns * 1e-9,
-            ttft_p50_ms=ttft_p50,
-            ttft_p99_ms=ttft_p99,
-            tpot_p50_ms=tpot_p50,
-            tpot_p99_ms=tpot_p99,
-            sched_ttft_p99_ms=(
-                float(np.percentile(sched_ttft, 99)) * 1e-6
-                if sched_ttft.size else 0.0
-            ),
-            sched_tpot_p99_ms=(
-                float(np.percentile(sched_tpot, 99)) * 1e-6
-                if sched_tpot.size else 0.0
-            ),
-            residency_mean=(
-                stats.residency_wsum / stats.dt_sum if stats.dt_sum else 1.0
-            ),
-            pages_spilled=pages_spilled,
-            pages_allocated=pages_allocated,
-            kv_spill_read_frac=(
-                stats.kv_rd_bytes_dram / kv_rd_total if kv_rd_total else 0.0
-            ),
-            bank_conflict_rate=result.bank_conflict_rate,
-            mean_queue_depth=result.mean_queue_depth,
-            bytes=trace_byte_counts(trace, system),
-            sim=result,
-        ))
+            ttft_p50, ttft_p99 = _percentiles_ms(ttft)
+            tpot_p50, tpot_p99 = _percentiles_ms(tpot)
+            reports.append(ServeReport(
+                n_requests=len(requests),
+                completed=len(finished),
+                n_steps=stats.n_steps,
+                offered_qps=offered_qps,
+                achieved_qps=(
+                    len(finished) / (span_ns * 1e-9) if span_ns else 0.0
+                ),
+                span_s=span_ns * 1e-9,
+                ttft_p50_ms=ttft_p50,
+                ttft_p99_ms=ttft_p99,
+                tpot_p50_ms=tpot_p50,
+                tpot_p99_ms=tpot_p99,
+                sched_ttft_p99_ms=(
+                    float(np.percentile(sched_ttft, 99)) * 1e-6
+                    if sched_ttft.size else 0.0
+                ),
+                sched_tpot_p99_ms=(
+                    float(np.percentile(sched_tpot, 99)) * 1e-6
+                    if sched_tpot.size else 0.0
+                ),
+                residency_mean=(
+                    stats.residency_wsum / stats.dt_sum if stats.dt_sum else 1.0
+                ),
+                pages_spilled=pages_spilled,
+                pages_allocated=pages_allocated,
+                kv_spill_read_frac=(
+                    stats.kv_rd_bytes_dram / kv_rd_total if kv_rd_total else 0.0
+                ),
+                bank_conflict_rate=result.bank_conflict_rate,
+                mean_queue_depth=result.mean_queue_depth,
+                bytes=trace_byte_counts(trace, system),
+                sim=result,
+            ))
     return reports

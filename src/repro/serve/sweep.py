@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.core.workload import NLP_TABLE_V, NLPModelSpec
 from repro.faults import FaultConfig, derate_system, fault_model_for
+from repro.obs import core as obs
 from repro.sim.engine import SimConfig, resolve_backend
 from repro.sim.trace import ServingConfig, arrivals_at_qps, draw_request_shape
 from repro.spec import build_system, tech_group
@@ -187,6 +188,9 @@ def sweep_serving_grid(
     ``score_s`` (trace build + batched replay + report) — the benchmark
     harness uses it to separate the serving-loop speedup from the shared
     replay cost.
+    With :mod:`repro.obs` enabled the grid runs inside a ``sweep`` span and
+    the same split is recorded as spans at the same boundaries (``loop``,
+    ``score`` and the phases below them; see ``docs/observability.md``).
 
     ``recorder`` (a :class:`repro.obs.TimelineRecorder`) records the *first*
     grid point only — its serving loop and its first technology's replay —
@@ -209,42 +213,75 @@ def sweep_serving_grid(
     rows: list[SweepRow] = []
     rec_pending = recorder  # consumed by the first grid point
     fleet_mode = not spec.fleet.trivial
-    for cap in spec.capacities_mb:
-        for qps in spec.qps:
-            cfg = dataclasses.replace(spec.serving, arrival_rate_rps=qps)
-            rec, rec_pending = rec_pending, None
-            if fleet_mode:
-                rows.extend(_fleet_grid_point(
-                    spec, nlp, cfg, cap, qps, mode, backend,
-                    interarrival_std, prompts, decodes,
-                    n_dram_channels, n_prefetch_channels, lowering,
-                    timing, rec,
-                ))
-                continue
-            if mode == "exact":
-                for tech in spec.technologies:
-                    system = build_system(tech, cap)
-                    # sim_config=None reproduces the closed loop's own
-                    # default (4x-cadence coalescing, no kind stats); only a
-                    # non-default replay backend needs an explicit config.
-                    _, rep = closed_loop_serving(
-                        system, nlp, cfg, spec.engine,
-                        sim_config=(None if backend == "numpy" else
-                                    _sim_config(system, nlp, cfg, spec.engine,
-                                                backend)),
-                        n_dram_channels=n_dram_channels,
-                        n_prefetch_channels=n_prefetch_channels,
-                        lowering=lowering,
-                        timing=timing,
-                        recorder=rec,
-                        faults=spec.faults,
-                    )
-                    rec = None
-                    rows.append(SweepRow(tech, cap, qps, False, rep))
-                continue
+    with obs.span("sweep"):
+        for cap in spec.capacities_mb:
+            for qps in spec.qps:
+                cfg = dataclasses.replace(spec.serving, arrival_rate_rps=qps)
+                rec, rec_pending = rec_pending, None
+                if fleet_mode:
+                    rows.extend(_fleet_grid_point(
+                        spec, nlp, cfg, cap, qps, mode, backend,
+                        interarrival_std, prompts, decodes,
+                        n_dram_channels, n_prefetch_channels, lowering,
+                        timing, rec,
+                    ))
+                elif mode == "exact":
+                    rows.extend(_exact_grid_point(
+                        spec, nlp, cfg, cap, qps, backend,
+                        n_dram_channels, n_prefetch_channels, lowering,
+                        timing, rec,
+                    ))
+                else:
+                    rows.extend(_shared_grid_point(
+                        spec, nlp, cfg, cap, qps, backend,
+                        interarrival_std, prompts, decodes,
+                        n_dram_channels, n_prefetch_channels, lowering,
+                        timing, rec,
+                    ))
+    return rows
 
-            # One scheduler + allocator + lowering pass per (qps, capacity).
-            t0 = time.perf_counter()
+
+def _exact_grid_point(spec, nlp, cfg, cap, qps, backend, n_dram_channels,
+                      n_prefetch_channels, lowering, timing, rec
+                      ) -> list[SweepRow]:
+    """One (capacity, qps) point, every technology on its own closed loop."""
+    out = []
+    for tech in spec.technologies:
+        system = build_system(tech, cap)
+        # sim_config=None reproduces the closed loop's own default
+        # (4x-cadence coalescing, no kind stats); only a non-default replay
+        # backend needs an explicit config.
+        _, rep = closed_loop_serving(
+            system, nlp, cfg, spec.engine,
+            sim_config=(None if backend == "numpy" else
+                        _sim_config(system, nlp, cfg, spec.engine, backend)),
+            n_dram_channels=n_dram_channels,
+            n_prefetch_channels=n_prefetch_channels,
+            lowering=lowering,
+            timing=timing,
+            recorder=rec,
+            faults=spec.faults,
+        )
+        rec = None
+        out.append(SweepRow(tech, cap, qps, False, rep))
+    return out
+
+
+def _shared_grid_point(spec, nlp, cfg, cap, qps, backend, interarrival_std,
+                       prompts, decodes, n_dram_channels, n_prefetch_channels,
+                       lowering, timing, rec) -> list[SweepRow]:
+    """One (capacity, qps) point off one shared schedule, all technologies.
+
+    Spans (:mod:`repro.obs`): ``loop`` (``schedule``: scheduler, allocator,
+    lowering; ``price``: neutral columns and per-technology pricing) and
+    ``score`` (``trace``, then :func:`score_shared_batch`), at the same
+    boundaries as ``timing``; ``fallback`` around each uncertified
+    technology's own closed loop.
+    """
+    # One scheduler + allocator + lowering pass per (qps, capacity).
+    t0 = time.perf_counter()
+    with obs.span("loop"):
+        with obs.span("schedule"):
             arrivals = arrivals_at_qps(interarrival_std, qps)
             ref_system = build_system(spec.technologies[0], cap)
             dram = ref_system.dram  # shared by every technology on the grid
@@ -256,11 +293,13 @@ def sweep_serving_grid(
                                              spec.engine)
             blocks_list, dts, stats = _shared_run(model, sched, lowering,
                                                   t_dram_acc_ns, recorder=rec)
+        with obs.span("price"):
             # Flatten the run's blocks once (class-major neutral columns),
-            # then price every technology off the same columns.  The shared
-            # clock already carries the (tech-invariant) DRAM busy term;
-            # only the per-bank GLB busy time can push a technology off the
-            # shared schedule — the pricing certificate checks every step.
+            # then price every technology off the same columns.  The
+            # shared clock already carries the (tech-invariant) DRAM busy
+            # term; only the per-bank GLB busy time can push a technology
+            # off the shared schedule — the pricing certificate checks
+            # every step.
             run = NeutralRun(blocks_list, dts, model,
                              n_dram_channels, n_prefetch_channels)
             # Iso-reliability pricing: each technology prices its derated
@@ -272,65 +311,65 @@ def sweep_serving_grid(
                 for tech in spec.technologies
             ]
             pricings = [
-                run.price(system,
-                          fault_model_for(system, spec.faults))
+                run.price(system, fault_model_for(system, spec.faults))
                 for system in tech_systems
             ]
-            timing["loop_s"] += time.perf_counter() - t0
-            sim_config = SimConfig(
-                coalesce_window_ns=4 * model.interval_ns, backend=backend,
-                kind_stats=False,
-            )
+    timing["loop_s"] += time.perf_counter() - t0
+    sim_config = SimConfig(
+        coalesce_window_ns=4 * model.interval_ns, backend=backend,
+        kind_stats=False,
+    )
 
-            # All certified technologies replay in one batched pass.
-            t0 = time.perf_counter()
-            certified = [(tech, p) for tech, p in
-                         zip(spec.technologies, pricings) if p.certified]
-            shared_reports: dict[str, ServeReport] = {}
-            if certified:
+    # All certified technologies replay in one batched pass.
+    t0 = time.perf_counter()
+    with obs.span("score"):
+        certified = [(tech, p) for tech, p in
+                     zip(spec.technologies, pricings) if p.certified]
+        shared_reports: dict[str, ServeReport] = {}
+        if certified:
+            with obs.span("trace"):
                 traces = [
                     run.build_trace(p, serving_run_meta(
                         nlp, cfg, spec.engine, p.system, model, stats,
                         lowering, schedule="shared"))
                     for _, p in certified
                 ]
-                reports = score_shared_batch(
-                    traces, [p.system for _, p in certified], sched, model,
-                    stats, sim_config,
-                    # The recorder taps the first technology's replay only
-                    # when that technology is certified (first certified
-                    # trace == first technology then).
-                    recorder=(rec if pricings[0].certified else None),
-                )
-                shared_reports = {
-                    tech: rep for (tech, _), rep in zip(certified, reports)
-                }
-            timing["score_s"] += time.perf_counter() - t0
+            reports = score_shared_batch(
+                traces, [p.system for _, p in certified], sched, model,
+                stats, sim_config,
+                # The recorder taps the first technology's replay only when
+                # that technology is certified (first certified trace ==
+                # first technology then).
+                recorder=(rec if pricings[0].certified else None),
+            )
+            shared_reports = {
+                tech: rep for (tech, _), rep in zip(certified, reports)
+            }
+    timing["score_s"] += time.perf_counter() - t0
 
-            for tech, pricing in zip(spec.technologies, pricings):
-                if pricing.certified:
-                    rows.append(SweepRow(tech, cap, qps, True,
-                                         shared_reports[tech]))
-                else:
-                    # Congestion would have stretched this technology's
-                    # steps: replay its own closed loop (still
-                    # block-lowered).  The shared loop already recorded this
-                    # grid point's lifecycles, so the fallback only taps the
-                    # replay.  The closed loop derates the base system
-                    # itself, so it gets the registry build, not the
-                    # already-derated pricing system.
-                    _, rep = closed_loop_serving(
-                        build_system(tech, cap), nlp, cfg, spec.engine,
-                        sim_config=sim_config,
-                        n_dram_channels=n_dram_channels,
-                        n_prefetch_channels=n_prefetch_channels,
-                        lowering=lowering,
-                        timing=timing,
-                        faults=spec.faults,
-                    )
-                    rows.append(SweepRow(tech, cap, qps, False, rep))
-            rec = None
-    return rows
+    out = []
+    for tech, pricing in zip(spec.technologies, pricings):
+        if pricing.certified:
+            out.append(SweepRow(tech, cap, qps, True, shared_reports[tech]))
+            continue
+        # Congestion would have stretched this technology's steps: replay
+        # its own closed loop (still block-lowered).  The shared loop
+        # already recorded this grid point's lifecycles, so the fallback
+        # only taps the replay.  The closed loop derates the base system
+        # itself, so it gets the registry build, not the already-derated
+        # pricing system.
+        with obs.span("fallback"):
+            _, rep = closed_loop_serving(
+                build_system(tech, cap), nlp, cfg, spec.engine,
+                sim_config=sim_config,
+                n_dram_channels=n_dram_channels,
+                n_prefetch_channels=n_prefetch_channels,
+                lowering=lowering,
+                timing=timing,
+                faults=spec.faults,
+            )
+        out.append(SweepRow(tech, cap, qps, False, rep))
+    return out
 
 
 def _sim_config(system, nlp, cfg, engine, backend) -> SimConfig:
@@ -406,35 +445,42 @@ def _fleet_grid_point(
     # interleaving carries the same outage/requeue sequence as the exact
     # fleet whenever the certificate holds).
     t0 = time.perf_counter()
-    arrivals = arrivals_at_qps(interarrival_std, qps)
-    ref_system = build_system(spec.technologies[0], cap)
-    dram = ref_system.dram  # shared by every technology on the grid
-    t_dram_acc_ns = dram.access_bytes / (dram.bandwidth_gb_s * 1e9) * 1e9
-    fleet = Fleet(ref_system, nlp, cfg, spec.engine, spec.fleet,
-                  lowering=lowering, recorder=rec, faults=faults)
+    with obs.span("loop"):
+        with obs.span("schedule"):
+            arrivals = arrivals_at_qps(interarrival_std, qps)
+            ref_system = build_system(spec.technologies[0], cap)
+            dram = ref_system.dram  # shared by every technology on the grid
+            t_dram_acc_ns = (
+                dram.access_bytes / (dram.bandwidth_gb_s * 1e9) * 1e9
+            )
+            fleet = Fleet(ref_system, nlp, cfg, spec.engine, spec.fleet,
+                          lowering=lowering, recorder=rec, faults=faults)
 
-    def shared_dt(replica, blocks):
-        decode_ns = replica.model.interval_ns if blocks.has_decode else 0.0
-        # Same accumulation order as TechPricer.price_step, so the value is
-        # bit-identical to the exact fleet's dram_ns term.
-        dram_acc = 0.0
-        if blocks.dram_rd_acc.size:
-            dram_acc += float(blocks.dram_rd_acc.sum())
-        if blocks.dram_wr_acc.size:
-            dram_acc += float(blocks.dram_wr_acc.sum())
-        return max(decode_ns, blocks.prefill_ns, dram_acc * t_dram_acc_ns)
+            def shared_dt(replica, blocks):
+                decode_ns = (replica.model.interval_ns if blocks.has_decode
+                             else 0.0)
+                # Same accumulation order as TechPricer.price_step, so the
+                # value is bit-identical to the exact fleet's dram_ns term.
+                dram_acc = 0.0
+                if blocks.dram_rd_acc.size:
+                    dram_acc += float(blocks.dram_rd_acc.sum())
+                if blocks.dram_wr_acc.size:
+                    dram_acc += float(blocks.dram_wr_acc.sum())
+                return max(decode_ns, blocks.prefill_ns,
+                           dram_acc * t_dram_acc_ns)
 
-    fleet.run(arrivals, prompts, decodes, shared_dt)
-    model0 = fleet.replicas[0].model
-    run = NeutralRun(fleet.blocks_list, fleet.dts_array, model0,
-                     n_dram_channels, n_prefetch_channels,
-                     n_replicas=fleet.capacity)
-    tech_systems = [derate_system(build_system(tech, cap), faults)
-                    for tech in spec.technologies]
-    fms = [fault_model_for(system, faults, n_replicas=fleet.capacity)
-           for system in tech_systems]
-    pricings = [run.price(system, fm)
-                for system, fm in zip(tech_systems, fms)]
+            fleet.run(arrivals, prompts, decodes, shared_dt)
+        with obs.span("price"):
+            model0 = fleet.replicas[0].model
+            run = NeutralRun(fleet.blocks_list, fleet.dts_array, model0,
+                             n_dram_channels, n_prefetch_channels,
+                             n_replicas=fleet.capacity)
+            tech_systems = [derate_system(build_system(tech, cap), faults)
+                            for tech in spec.technologies]
+            fms = [fault_model_for(system, faults, n_replicas=fleet.capacity)
+                   for system in tech_systems]
+            pricings = [run.price(system, fm)
+                        for system, fm in zip(tech_systems, fms)]
     timing["loop_s"] += time.perf_counter() - t0
     sim_config = SimConfig(
         coalesce_window_ns=4 * model0.interval_ns, backend=backend,
@@ -442,37 +488,39 @@ def _fleet_grid_point(
     )
 
     t0 = time.perf_counter()
-    mean_alive = fleet.mean_alive()
-    certified = [(tech, p) for tech, p in
-                 zip(spec.technologies, pricings) if p.certified]
-    shared_fleet: dict[str, FleetReport] = {}
-    if certified:
-        traces = [
-            run.build_trace(p, serving_run_meta(
-                nlp, cfg, spec.engine, p.system, model0, fleet.stats,
-                lowering, schedule="shared", **fleet.fleet_meta()),
-                leakage_scale=mean_alive)
-            for _, p in certified
-        ]
-        reports = score_shared_batch(
-            traces, [p.system for _, p in certified], None, None,
-            fleet.stats, sim_config,
-            recorder=(rec if pricings[0].certified else None),
-            requests=fleet.logical,
-            finished=fleet.finished_logical,
-            arrival_by_rid=fleet.arrival_by_rid,
-            offered_qps=cfg.arrival_rate_rps,
-            pages_spilled=fleet.pages_spilled(),
-            pages_allocated=fleet.pages_allocated(),
-        )
-        fm_by_tech = dict(zip(spec.technologies, fms))
-        shared_fleet = {
-            tech: fleet.finalize(
-                rep, p.system,
-                fault_stats=(fm_by_tech[tech].stats()
-                             if fm_by_tech[tech] is not None else None))
-            for (tech, p), rep in zip(certified, reports)
-        }
+    with obs.span("score"):
+        mean_alive = fleet.mean_alive()
+        certified = [(tech, p) for tech, p in
+                     zip(spec.technologies, pricings) if p.certified]
+        shared_fleet: dict[str, FleetReport] = {}
+        if certified:
+            with obs.span("trace"):
+                traces = [
+                    run.build_trace(p, serving_run_meta(
+                        nlp, cfg, spec.engine, p.system, model0, fleet.stats,
+                        lowering, schedule="shared", **fleet.fleet_meta()),
+                        leakage_scale=mean_alive)
+                    for _, p in certified
+                ]
+            reports = score_shared_batch(
+                traces, [p.system for _, p in certified], None, None,
+                fleet.stats, sim_config,
+                recorder=(rec if pricings[0].certified else None),
+                requests=fleet.logical,
+                finished=fleet.finished_logical,
+                arrival_by_rid=fleet.arrival_by_rid,
+                offered_qps=cfg.arrival_rate_rps,
+                pages_spilled=fleet.pages_spilled(),
+                pages_allocated=fleet.pages_allocated(),
+            )
+            fm_by_tech = dict(zip(spec.technologies, fms))
+            shared_fleet = {
+                tech: fleet.finalize(
+                    rep, p.system,
+                    fault_stats=(fm_by_tech[tech].stats()
+                                 if fm_by_tech[tech] is not None else None))
+                for (tech, p), rep in zip(certified, reports)
+            }
     timing["score_s"] += time.perf_counter() - t0
 
     out = []
@@ -484,13 +532,14 @@ def _fleet_grid_point(
             # Congestion would have re-interleaved this technology's fleet:
             # run its own exact fleet loop (off the registry build — the
             # exact loop derates the base system itself).
-            _, fr = fleet_serving(
-                build_system(tech, cap), nlp, cfg, spec.engine, spec.fleet,
-                sim_config=sim_config,
-                n_dram_channels=n_dram_channels,
-                n_prefetch_channels=n_prefetch_channels,
-                lowering=lowering, timing=timing,
-                faults=faults,
-            )
+            with obs.span("fallback"):
+                _, fr = fleet_serving(
+                    build_system(tech, cap), nlp, cfg, spec.engine,
+                    spec.fleet, sim_config=sim_config,
+                    n_dram_channels=n_dram_channels,
+                    n_prefetch_channels=n_prefetch_channels,
+                    lowering=lowering, timing=timing,
+                    faults=faults,
+                )
             out.append(SweepRow(tech, cap, qps, False, fr.report, fleet=fr))
     return out

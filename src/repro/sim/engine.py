@@ -30,6 +30,11 @@ offset encode/decode are single elementwise IEEE ops — see
 stream (the serving sweep's per-technology traces) in a single batched
 pass — shared time sort, batched per-row segment bookkeeping, and one fused
 device scan instead of per-technology host round-trips.
+
+Both replays open one ``replay`` span (:mod:`repro.obs`) around the whole
+call, and a ``sort`` span inside it around the sort and the segment
+bookkeeping; the scan's own phases nest below (see
+``repro.kernels.segmented_replay.ops``).
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import difflib
 
 import numpy as np
 
+from repro.obs import core as obs
 from repro.sim.trace import (
     EXPOSED_KINDS,
     KIND_DRAM_RD,
@@ -257,30 +263,38 @@ def replay_schedule(
             kind=np.empty(0, kind.dtype), start_ns=e, finish_ns=e, wait_ns=e,
             queue_depth=np.empty(0, np.int64), order=np.empty(0, np.int64),
         )
-    # Serving traces append steps in clock order, so ``t_issue`` is already
-    # nondecreasing; a stable radix argsort on the (small-int) resource ids
-    # then yields exactly ``lexsort((t_issue, resource))`` — same permutation,
-    # input order preserved within (resource, t_issue) ties — at O(n) instead
-    # of a comparison sort on two float/int key columns.
-    if t_issue.size > 1 and t_issue[0] <= t_issue[-1] and np.all(np.diff(t_issue) >= 0):
-        order = np.argsort(resource, kind="stable")
-    else:
-        order = np.lexsort((t_issue, resource))
-    res_s = resource[order]
-    t_s = t_issue[order]
-    svc_s = service[order]
-    kind_s = kind[order]
+    with obs.span("replay"):
+        return _replay_1d(t_issue, resource, service, kind, backend, n)
 
-    new_seg = np.empty(n, bool)
-    new_seg[0] = True
-    new_seg[1:] = res_s[1:] != res_s[:-1]
-    seg_id = np.cumsum(new_seg) - 1
-    cs = np.cumsum(svc_s)
-    seg_first = np.flatnonzero(new_seg)
-    seg_len = np.diff(np.append(seg_first, n))
-    seg_base = np.repeat(cs[seg_first] - svc_s[seg_first], seg_len)
-    s_local = cs - seg_base  # inclusive in-segment cumulative service
-    v = t_s - (s_local - svc_s)
+
+def _replay_1d(t_issue, resource, service, kind, backend, n) -> ReplaySchedule:
+    with obs.span("sort"):
+        # Serving traces append steps in clock order, so ``t_issue`` is
+        # already nondecreasing; a stable radix argsort on the (small-int)
+        # resource ids then yields exactly ``lexsort((t_issue, resource))``
+        # — same permutation, input order preserved within (resource,
+        # t_issue) ties — at O(n) instead of a comparison sort on two
+        # float/int key columns.
+        if (t_issue.size > 1 and t_issue[0] <= t_issue[-1]
+                and np.all(np.diff(t_issue) >= 0)):
+            order = np.argsort(resource, kind="stable")
+        else:
+            order = np.lexsort((t_issue, resource))
+        res_s = resource[order]
+        t_s = t_issue[order]
+        svc_s = service[order]
+        kind_s = kind[order]
+
+        new_seg = np.empty(n, bool)
+        new_seg[0] = True
+        new_seg[1:] = res_s[1:] != res_s[:-1]
+        seg_id = np.cumsum(new_seg) - 1
+        cs = np.cumsum(svc_s)
+        seg_first = np.flatnonzero(new_seg)
+        seg_len = np.diff(np.append(seg_first, n))
+        seg_base = np.repeat(cs[seg_first] - svc_s[seg_first], seg_len)
+        s_local = cs - seg_base  # inclusive in-segment cumulative service
+        v = t_s - (s_local - svc_s)
     big = float(v.max() - v.min()) + 1.0
     running_max = _cummax(v + seg_id * big, backend) - seg_id * big
     finish = s_local + running_max
@@ -376,27 +390,35 @@ def replay_schedule_batch(
             queue_depth=np.empty((R, 0), np.int64),
             order=np.empty((R, 0), np.int64),
         )
-    if n > 1 and t_issue[0] <= t_issue[-1] and np.all(np.diff(t_issue) >= 0):
-        order = np.argsort(resource, axis=1, kind="stable")
-    else:
-        ord1 = np.argsort(t_issue, kind="stable")
-        order = ord1[np.argsort(resource[:, ord1], axis=1, kind="stable")]
-    res_s = np.take_along_axis(resource, order, axis=1)
-    svc_s = np.take_along_axis(service, order, axis=1)
-    t_s = t_issue[order]
-    kind_s = kind[order]
+    with obs.span("replay"):
+        return _replay_batch(t_issue, resource, service, kind, backend, R, n)
 
-    new_seg = np.empty((R, n), bool)
-    new_seg[:, 0] = True
-    new_seg[:, 1:] = res_s[:, 1:] != res_s[:, :-1]
-    seg_id = np.cumsum(new_seg, axis=1) - 1
-    cs = np.cumsum(svc_s, axis=1)
-    seg_base = np.maximum.accumulate(
-        np.where(new_seg, cs - svc_s, -np.inf), axis=1
-    )
-    s_local = cs - seg_base
-    v = t_s - (s_local - svc_s)
-    big = (v.max(axis=1) - v.min(axis=1)) + 1.0
+
+def _replay_batch(t_issue, resource, service, kind, backend, R, n
+                  ) -> BatchedReplaySchedule:
+    with obs.span("sort"):
+        if (n > 1 and t_issue[0] <= t_issue[-1]
+                and np.all(np.diff(t_issue) >= 0)):
+            order = np.argsort(resource, axis=1, kind="stable")
+        else:
+            ord1 = np.argsort(t_issue, kind="stable")
+            order = ord1[np.argsort(resource[:, ord1], axis=1, kind="stable")]
+        res_s = np.take_along_axis(resource, order, axis=1)
+        svc_s = np.take_along_axis(service, order, axis=1)
+        t_s = t_issue[order]
+        kind_s = kind[order]
+
+        new_seg = np.empty((R, n), bool)
+        new_seg[:, 0] = True
+        new_seg[:, 1:] = res_s[:, 1:] != res_s[:, :-1]
+        seg_id = np.cumsum(new_seg, axis=1) - 1
+        cs = np.cumsum(svc_s, axis=1)
+        seg_base = np.maximum.accumulate(
+            np.where(new_seg, cs - svc_s, -np.inf), axis=1
+        )
+        s_local = cs - seg_base
+        v = t_s - (s_local - svc_s)
+        big = (v.max(axis=1) - v.min(axis=1)) + 1.0
 
     if backend == "numpy":
         from repro.kernels.segmented_replay.ref import replay_scan_np

@@ -131,6 +131,148 @@ def test_enable_reset_disable_lifecycle():
     assert not obs.enabled()
 
 
+class _Marks:
+    """An ``annotate`` factory that logs each path it is entered with."""
+
+    def __init__(self):
+        self.entered, self.exited = [], 0
+
+    def __call__(self, path):
+        marks = self
+
+        class _Mark:
+            def __enter__(self):
+                marks.entered.append(path)
+                return self
+
+            def __exit__(self, *exc):
+                marks.exited += 1
+                return False
+
+        return _Mark()
+
+
+def test_annotate_is_entered_with_the_full_path_only_when_enabled():
+    marks = _Marks()
+    with obs.span("sweep"):  # disabled: the no-op, annotate never called
+        pass
+    obs.enable(annotate=marks)
+    with obs.span("sweep"):
+        with obs.span("score"):
+            with obs.span("replay"):
+                pass
+    assert marks.entered == ["sweep", "sweep/score", "sweep/score/replay"]
+    assert marks.exited == 3
+    assert set(obs.phase_times()) == set(marks.entered)
+    obs.reset()  # keeps annotating
+    with obs.span("a"):
+        pass
+    obs.enable()  # without annotate: stops annotating
+    with obs.span("b"):
+        pass
+    obs.disable()
+    with obs.span("c"):
+        pass
+    assert marks.entered[3:] == ["a"] and marks.exited == 4
+
+
+_SWEEP_SPANS = {
+    "sweep", "sweep/loop", "sweep/loop/schedule", "sweep/loop/price",
+    "sweep/score", "sweep/score/trace", "sweep/score/coalesce",
+    "sweep/score/replay", "sweep/score/replay/sort", "sweep/score/distill",
+}
+_DEVICE_SPANS = {
+    "sweep/score/replay/pad", "sweep/score/replay/encode",
+    "sweep/score/replay/device/cummax", "sweep/score/replay/decode",
+    "sweep/score/replay/device/search",
+}
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_sweep_spans_and_counters_leave_rows_bit_identical(backend,
+                                                          monkeypatch):
+    """The sweep's span tree and lane counters, recorded with the profiler's
+    annotation on; rows are bitwise those of a run with obs off."""
+    jax = pytest.importorskip("jax")
+    from repro.serve import ServingGridSpec, sweep_serving_grid
+
+    grid = ServingGridSpec(qps=(200.0, 400.0), capacities_mb=(32.0,),
+                           technologies=("sot_opt", "sram"), model="gpt2",
+                           serving=_SERVE_CFG, engine=_ENGINE_CFG)
+    rows_off = sweep_serving_grid(grid, backend=backend)
+    obs.enable()
+    rows_on = sweep_serving_grid(grid, backend=backend)
+    obs.disable()
+
+    counted = []
+    real_count = obs_core.count
+
+    def count(name, n=1):
+        counted.append((name, n))
+        real_count(name, n)
+
+    monkeypatch.setattr(obs_core, "count", count)
+    obs.enable(annotate=jax.profiler.TraceAnnotation)
+    rows_ann = sweep_serving_grid(grid, backend=backend)
+    snap = obs.snapshot()
+
+    for rows in (rows_on, rows_ann):
+        assert len(rows) == len(rows_off)
+        for a, b in zip(rows_off, rows):
+            assert (a.technology, a.qps, a.shared) == (
+                b.technology, b.qps, b.shared)
+            assert _deep_equal(dataclasses.asdict(a.report),
+                               dataclasses.asdict(b.report))
+    assert all(r.shared for r in rows_off)
+
+    spans = snap["spans"]
+    want = _SWEEP_SPANS | (_DEVICE_SPANS if backend == "jax" else set())
+    assert set(spans) == want
+    assert spans["sweep"]["calls"] == 1
+    assert spans["sweep/score/replay"]["calls"] == len(grid.qps)
+    if backend == "numpy":
+        assert snap["counters"] == {}
+        return
+    from repro.kernels.segmented_replay.ops import _next_pow2
+
+    assert spans["sweep/score/replay/device/search"]["calls"] == len(grid.qps)
+    lanes = [n for name, n in counted if name == "replay/lanes"]
+    padded = [n for name, n in counted if name == "replay/lanes_padded"]
+    assert len(lanes) == len(padded) == len(grid.qps)
+    R = len(grid.technologies)
+    for real, pad in zip(lanes, padded):
+        assert pad >= real > 0 and pad % R == 0 and real % R == 0
+        width = pad // R
+        assert width >= 4096 and width & (width - 1) == 0
+        assert width == _next_pow2(real // R)
+    assert snap["counters"] == {"replay/lanes": sum(lanes),
+                                "replay/lanes_padded": sum(padded)}
+
+
+def test_sweep_spans_land_on_the_profilers_host_line(tmp_path):
+    """Under a running profiler trace, every span the sweep records is an
+    event of the trace's host plane, named by its full path."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    from repro.serve import ServingGridSpec, sweep_serving_grid
+
+    grid = ServingGridSpec(qps=(400.0,), capacities_mb=(32.0,),
+                           technologies=("sot_opt", "sram"), model="gpt2",
+                           serving=_SERVE_CFG, engine=_ENGINE_CFG)
+    with jax.profiler.trace(str(tmp_path)):
+        obs.enable(annotate=jax.profiler.TraceAnnotation)
+        sweep_serving_grid(grid, backend="jax")
+        spans = set(obs.snapshot()["spans"])
+        obs.disable()
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    host = {e.name for plane in ProfileData.from_file(str(pb)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert spans == _SWEEP_SPANS | _DEVICE_SPANS
+    assert spans <= host
+
+
 # ---------------------------------------------------------------------------
 # manifest: provenance stamping
 # ---------------------------------------------------------------------------
