@@ -11,6 +11,18 @@ reference path in ``repro.sim.engine`` / :mod:`.ref`:
   arithmetic on the host, its two order operations (the running max and the
   queue-depth ``searchsorted``) on the device.
 
+The queue-depth ``searchsorted`` is a bitonic merge rank
+(:func:`_merge_rank`), made only of elementwise passes and shifts: no
+gather, scatter or sort.  It merges each row's finish keys with its
+reversed issue keys by the bitonic half-cleaner stages, counts with a
+running sum of tags how many finish keys precede each issue key, and
+routes the counts back by replaying the stages' swaps in reverse.  It
+needs both rows sorted, as ``numpy.searchsorted`` in :mod:`.ref` needs its
+first: within a bank segment finish times are nondecreasing (a running max
+plus a nondecreasing local sum) and so are issue times (events are sorted
+by ``(resource, t_issue)``); the ``seg_id * big2`` offsets keep segments
+apart; and the neutral pad tail sorts above every real lane.
+
 Only order operations run on the device, and only on 32-bit integers.
 Each float64 is mapped on the host to an order-preserving int64 key (the
 IEEE bits with the magnitude bits flipped for negative values), split into
@@ -50,7 +62,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.segmented_replay.segmented_replay import (
+    LANES,
     PAIR_MIN,
+    ROWS,
     cummax_2d,
     lexmax,
 )
@@ -58,6 +72,7 @@ from repro.obs import core as obs
 
 DEFAULT_CHUNK = 1024
 _MAGNITUDE = np.int64(0x7FFF_FFFF_FFFF_FFFF)
+TILE = ROWS * LANES  # lanes of one (8, 128) int32 tile
 
 
 def _auto_interpret() -> bool:
@@ -88,7 +103,11 @@ def _to_pair(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     lexicographically as the floats do.
     """
     bits = np.ascontiguousarray(x, np.float64).view(np.int64)
-    k = bits ^ ((bits >> 63) & _MAGNITUDE)
+    return _split(bits ^ ((bits >> 63) & _MAGNITUDE))
+
+
+def _split(k: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """The int32 ``(hi, lo)`` words of int64 keys ``k``, in ``k``'s order."""
     return (k >> 32).astype(np.int32), ((k & 0xFFFF_FFFF) - 2**31).astype(np.int32)
 
 
@@ -120,30 +139,119 @@ def _cummax_lax(hi, lo):
     return hi, lo
 
 
+def _lt(a, b):
+    """Lexicographic ``a < b`` of ``(hi, lo, tag)`` int32 word triples."""
+    (ah, al, at), (bh, bl, bt) = a, b
+    return (ah < bh) | ((ah == bh) & ((al < bl) | ((al == bl) & (at < bt))))
+
+
+def _halves(x, h):
+    """The lower and upper ``h`` lanes of every ``2h`` block of ``x``.
+
+    ``x`` is ``(R, M, LANES)``, one row of ``M * LANES`` lanes per ``r``;
+    for ``h`` a multiple of ``TILE`` both halves are whole ``(8, 128)``
+    tiles, so the split is a reshape of major dimensions.
+    """
+    R, M, _ = x.shape
+    g = h // LANES
+    v = x.reshape(R, M // (2 * g), 2, g, LANES)
+    return v[:, :, 0], v[:, :, 1]
+
+
+def _partner(x, h):
+    """Each lane's partner ``h`` lanes away, and which lanes are lower.
+
+    For ``h`` below a tile the partner lies in the same ``(8, 128)`` tile:
+    ``h // 128`` sublanes away, or ``h`` lanes away within a 128-lane row,
+    so both are rotations of ``x`` and no lane pairs across a row.
+    """
+    axis, s = (1, h // LANES) if h >= LANES else (2, h)
+    lower = (jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) & s) == 0
+    return lower, jnp.where(lower, jnp.roll(x, -s, axis), jnp.roll(x, s, axis))
+
+
+def _swaps(words, h):
+    """Which pairs of the half-cleaner stage ``h`` are out of order.
+
+    One flag per pair (the lower half's shape) where ``h >= TILE``, else
+    one per lane, equal on both lanes of a pair.  Ties stay in place.
+    """
+    if h >= TILE:
+        lower, upper = zip(*(_halves(w, h) for w in words))
+        return _lt(upper, lower)
+    lower, partner = zip(*(_partner(w, h) for w in words))
+    return jnp.where(lower[0], _lt(partner, words), _lt(words, partner))
+
+
+def _exchange(x, h, swap):
+    """Swap the pairs of stage ``h`` that ``swap`` flags; its own inverse."""
+    if h >= TILE:
+        lower, upper = _halves(x, h)
+        return jnp.stack(
+            [jnp.where(swap, upper, lower), jnp.where(swap, lower, upper)], axis=2
+        ).reshape(x.shape)
+    return jnp.where(swap, _partner(x, h)[1], x)
+
+
+def _prefix_sum(x, axis):
+    """Inclusive running sum along ``axis`` by doubling shift-adds.
+
+    Exact in int32; used instead of ``jnp.cumsum`` for the reason
+    :func:`_cummax_lax` avoids ``lax.cummax``.
+    """
+    n, s = x.shape[axis], 1
+    while s < n:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (s, 0)
+        x = x + jnp.pad(jax.lax.slice_in_dim(x, 0, n - s, axis=axis), pad)
+        s *= 2
+    return x
+
+
 @_named_jit("replay_search")
-def _searchsorted_rows(a_hi, a_lo, q_hi, q_lo):
+def _merge_rank(a_hi, a_lo, q_hi, q_lo):
     """Row-wise ``searchsorted(a, q, side="left")`` over ``(hi, lo)`` pairs.
 
-    A binary search with lexicographic compares: the first index whose
-    ``a`` is not below ``q`` (``a`` sorted along each row).
-    """
-    n = a_hi.shape[1]
+    Both ``a`` and ``q`` must be sorted along each row, and their common
+    length ``n`` a power of two of at least 64.  A bitonic merge
+    rank, with no gather, scatter or sort:
 
-    def halve(_, bounds):
-        lo, hi = bounds
-        mid = (lo + hi) // 2
-        at = jnp.minimum(mid, n - 1)
-        m_hi = jnp.take_along_axis(a_hi, at, axis=1)
-        m_lo = jnp.take_along_axis(a_lo, at, axis=1)
-        below = (m_hi < q_hi) | ((m_hi == q_hi) & (m_lo < q_lo))
-        open_ = lo < hi
-        return (jnp.where(open_ & below, mid + 1, lo),
-                jnp.where(open_ & ~below, mid, hi))
+    1. per row, ``a`` (tag 1) followed by ``q`` reversed (tag 0) is a
+       bitonic sequence of ``m = 2n`` lanes;
+    2. the half-cleaner stages ``h = n, n/2, ..., 1`` sort it by
+       ``(hi, lo, tag)``, each keeping its swap flags; on a tie ``q``
+       (tag 0) precedes ``a``, so only ``a`` strictly below ``q`` precedes
+       it, which is ``side="left"``;
+    3. the running sum of the tags then holds, at each ``q`` lane, the
+       count of ``a`` below it;
+    4. replaying the stages' swaps in reverse order (``h = 1, ..., n``)
+       routes each count back to its own lane, and ``q``'s counts are
+       read from the second half, reversed.
+
+    Equal keys of one tag are interchangeable, so it does not matter that
+    the merge is not stable.  Rows are laid out ``(R, m // 128, 128)``.
+    """
+    R, n = a_hi.shape
+    m = 2 * n
+
+    def row(a, q):
+        return jnp.concatenate([a, q[:, ::-1]], axis=1).reshape(R, m // LANES, LANES)
 
     with jax.named_scope("replay_search"):
-        bounds = (jnp.zeros(q_hi.shape, jnp.int32),
-                  jnp.full(q_hi.shape, n, jnp.int32))
-        return jax.lax.fori_loop(0, n.bit_length(), halve, bounds)[0]
+        words = [row(a_hi, q_hi), row(a_lo, q_lo),
+                 row(jnp.ones_like(a_hi), jnp.zeros_like(q_hi))]
+        stages, h = [], n
+        while h:
+            swap = _swaps(words, h)
+            words = [_exchange(w, h, swap) for w in words]
+            stages.append((h, swap))
+            h //= 2
+        within = _prefix_sum(words[2], 2)  # along each 128-lane row
+        before = _prefix_sum(within[:, :, -1], 1) - within[:, :, -1]
+        count = within + before[:, :, None]
+        for h, swap in reversed(stages):
+            count = _exchange(count, h, swap)
+        return count.reshape(R, m)[:, n:][:, ::-1]
 
 
 def _device_cummax(hi, lo, scan, chunk, interpret):
@@ -164,10 +272,10 @@ def _device_cummax(hi, lo, scan, chunk, interpret):
 
 
 def _device_search(a_hi, a_lo, q_hi, q_lo) -> np.ndarray:
-    """:func:`_searchsorted_rows` on the device; blocks for the numpy result."""
+    """:func:`_merge_rank` on the device; blocks for the numpy result."""
     with obs.span("device/search"):
         pairs = [jnp.asarray(w) for w in (a_hi, a_lo, q_hi, q_lo)]
-        return np.asarray(_searchsorted_rows(*pairs))
+        return np.asarray(_merge_rank(*pairs))
 
 
 def cummax(
@@ -237,8 +345,8 @@ def replay_scan(
     """Batched replay scan; bitwise-equal to ``ref.replay_scan_np``.
 
     ``scan="lax"`` runs the running max as an XLA program, ``scan="pallas"``
-    through the chunked Pallas kernel; the depth ``searchsorted`` is XLA
-    either way.  Returns numpy ``(finish, start, wait, depth)``.
+    through the chunked Pallas kernel; the depth ``searchsorted`` is the XLA
+    merge rank either way.  Returns numpy ``(finish, start, wait, depth)``.
     """
     if interpret is None:
         interpret = _auto_interpret()

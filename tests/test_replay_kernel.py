@@ -256,6 +256,75 @@ def test_replay_scan_padding_is_neutral():
             np.testing.assert_array_equal(a, b, err_msg=f"{scan}/{name}")
 
 
+MERGE_CASES = ("ties", "long_runs", "equal_hi", "extreme_words",
+               "negative_floats", "pad_tail")
+
+
+def _merge_rank_case(case, R, n, rng):
+    """Sorted ``(a, q)`` rows for the depth search: float64 or int64 keys."""
+    def rows(draw):
+        return np.sort(draw((R, n)), axis=1)
+
+    if case == "ties":  # every q equal to some a, and runs of both
+        a = rows(lambda s: rng.integers(0, n // 4, s)).astype(np.int64)
+        return a, np.sort(np.take_along_axis(a, rng.integers(0, n, (R, n)), 1), 1)
+    if case == "long_runs":  # three distinct keys in all
+        return (rows(lambda s: rng.integers(0, 3, s)),
+                rows(lambda s: rng.integers(0, 3, s)))
+    if case == "equal_hi":  # one hi word; lo over its whole range
+        lo = lambda s: rng.integers(0, 2**32, s)  # noqa: E731
+        return (7 << 32) + rows(lo), (7 << 32) + rows(lo)
+    if case == "extreme_words":  # hi and lo words at PAIR_MIN and int32 max
+        words = np.array([0, 1, 2**32 - 2, 2**32 - 1], np.int64)
+        keys = np.sort(np.concatenate([
+            (-(2**31) << 32) + words, (5 << 32) + words, ((2**31 - 1) << 32) + words]))
+        return (rows(lambda s: rng.choice(keys, s)),
+                rows(lambda s: rng.choice(keys, s)))
+    if case == "negative_floats":
+        return (rows(lambda s: -rng.exponential(1e6, s)),
+                rows(lambda s: -rng.exponential(1e6, s)))
+    if case == "pad_tail":  # _pad_neutral's tail: one key above every real lane
+        a, q = rows(lambda s: rng.uniform(0, 1e5, s)), rows(lambda s: rng.uniform(0, 1e5, s))
+        real = n - n // 3
+        a[:, real:] = q[:, real:] = 2e5
+        return a, q
+    raise AssertionError(case)
+
+
+def _merge_rank_check(a, q):
+    from repro.kernels.segmented_replay.ops import _merge_rank, _split, _to_pair
+
+    words = _to_pair if a.dtype == np.float64 else _split
+    got = np.asarray(_merge_rank(*words(a), *words(q)))
+    ref = np.stack([np.searchsorted(a[r], q[r], side="left") for r in range(a.shape[0])])
+    np.testing.assert_array_equal(got, ref)
+
+
+@needs_jax
+@pytest.mark.parametrize("n", [4096, 8192])
+@pytest.mark.parametrize("R", [1, 4, 5])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_merge_rank_matches_searchsorted(case, R, n):
+    """The device depth search is ``searchsorted(side="left")`` per row."""
+    rng = np.random.default_rng([MERGE_CASES.index(case), R, n])
+    _merge_rank_check(*_merge_rank_case(case, R, n, rng))
+
+
+@needs_jax
+@settings(max_examples=10, deadline=None)
+@given(
+    R=st.integers(min_value=1, max_value=5),
+    seed=st.integers(min_value=0, max_value=2**20),
+    distinct=st.integers(min_value=1, max_value=5000),
+)
+def test_property_merge_rank(R, seed, distinct):
+    """Sorted float64 rows drawn from ``distinct`` values, shared by a and q."""
+    rng = np.random.default_rng(seed)
+    values = rng.normal(0.0, 1e4, distinct)
+    a, q = (np.sort(rng.choice(values, (R, 4096)), axis=1) for _ in range(2))
+    _merge_rank_check(a, q)
+
+
 # ---------------------------------------------------------------------------
 # Layer 3: batch vs per-row, per backend
 

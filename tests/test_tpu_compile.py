@@ -79,7 +79,7 @@ def test_replay_running_max_compiles(one_chip):
 
 def test_replay_depth_searchsorted_compiles(one_chip):
     x = _spec(one_chip, jnp.int32)
-    ops._searchsorted_rows.lower(x, x, x, x).compile()
+    ops._merge_rank.lower(x, x, x, x).compile()
 
 
 def test_pallas_cummax_compiles_to_mosaic(one_chip):
