@@ -24,18 +24,27 @@ by ``(resource, t_issue)``); the ``seg_id * big2`` offsets keep segments
 apart; and the neutral pad tail sorts above every real lane.
 
 Only order operations run on the device, and only on 32-bit integers.
-Each float64 is mapped on the host to an order-preserving int64 key (the
-IEEE bits with the magnitude bits flipped for negative values), split into
-an int32 ``(hi, lo)`` pair (:func:`_to_pair`), and the device compares
-pairs lexicographically.  The result then depends neither on the device's
-float64 nor on its int64, both of which a TPU emulates: on a v5e a float64
-round-trip already changes low bits, and XLA's running max over int64
-replay keys returned wrong words.  Everything that rounds — the offset
-encode/decode, finish/start/wait, the ``big2`` span — stays in numpy,
-operand for operand as in :mod:`.ref`, which also keeps multiplies (which
-XLA may contract into FMAs) and reassociating reductions off the device.
-The keys are never ``-0.0``: every keyed value is ``x + off`` with
-``off >= +0.0``, so key order and float order agree on every tie.
+The host hands each keyed float64 array over as its raw IEEE bits (a
+zero-copy ``int64`` view, never a float64 array), and each device program
+first maps every value's two 32-bit words to an order-preserving int32
+``(hi, lo)`` pair (:func:`_key_words`: the magnitude bits flipped for
+negative values, the low word offset so that signed order is unsigned
+order); the pairs compare lexicographically as the floats do.  The
+running max maps its result back by the same map, which is its own
+inverse, and returns the IEEE high and low words, which the host
+interleaves into float64 in one copy.  The device touches 64-bit values
+only to split them into words, by shift and convert; it never orders or
+adds them.  The result then depends
+neither on the device's float64 nor on its int64 arithmetic, both of
+which a TPU emulates: on a v5e a float64 round-trip already changes low
+bits, and XLA's running max over int64 replay keys returned wrong words.
+Everything that rounds — the offset encode/decode, finish/start/wait, the
+``big2`` span — stays in numpy, operand for operand as in :mod:`.ref`,
+which also keeps multiplies (which XLA may contract into FMAs) and
+reassociating reductions off the device.  The keys are never ``-0.0``:
+every keyed value is ``x + off`` with ``off >= +0.0``, so key order and
+float order agree on every tie.  :func:`.ref._to_pair` is the numpy
+reference of the word map.
 
 To bound recompiles across a sweep (event counts differ per grid point),
 ``replay_scan`` pads rows to the next power of two with a *neutral tail
@@ -45,14 +54,18 @@ big2 span, every real output, and every real queue depth are bit-identical
 to the unpadded computation (see ``_pad_neutral``).
 
 With ``repro.obs`` enabled, each host phase opens a span (``pad``,
-``encode``, ``decode``) and each device program one ``device/cummax`` or
-``device/search`` span, from the first transfer of its int32 pairs to the
-numpy result in hand (transfer in, kernel, transfer out); ``replay_scan``
-counts the lanes it gives the device (``replay/lanes``, R x n) and the
-padded lanes they fill (``replay/lanes_padded``, R x npad).  The device
-programs carry stable names (``replay_cummax``, ``replay_search``) in the
-profiler's trace: as module names (``jit_replay_cummax``,
-``jit_replay_search``) and as a ``jax.named_scope`` over their ops.
+``encode``, ``decode``: float work, zero-copy views and the running
+max's word interleave) and each device
+program one ``device/cummax`` or ``device/search`` span, from the first
+transfer of its key bits to the numpy result in hand (transfer in, word
+map and kernel, transfer out); ``replay_scan`` counts the lanes it gives
+the device (``replay/lanes``, R x n) and the padded lanes they fill
+(``replay/lanes_padded``, R x npad), and every device call the bytes it
+ships each way (``replay/bytes_to_device``, ``replay/bytes_from_device``).
+The device programs carry stable names (``replay_cummax``,
+``replay_search``) in the profiler's trace: as module names
+(``jit_replay_cummax``, ``jit_replay_search``) and as a
+``jax.named_scope`` over their ops.
 """
 
 from __future__ import annotations
@@ -61,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.device import x64
 from repro.kernels.segmented_replay.segmented_replay import (
     LANES,
     PAIR_MIN,
@@ -71,15 +85,15 @@ from repro.kernels.segmented_replay.segmented_replay import (
 from repro.obs import core as obs
 
 DEFAULT_CHUNK = 1024
-_MAGNITUDE = np.int64(0x7FFF_FFFF_FFFF_FFFF)
 TILE = ROWS * LANES  # lanes of one (8, 128) int32 tile
+_MAGNITUDE = np.int32(0x7FFF_FFFF)
 
 
 def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _named_jit(name: str):
+def _named_jit(name: str, **jit_kwargs):
     """``jax.jit`` under a stable program name.
 
     The compiled module is ``jit_<name>``, the name a profiler trace gives
@@ -89,36 +103,31 @@ def _named_jit(name: str):
     """
     def wrap(fn):
         fn.__name__ = fn.__qualname__ = name
-        return jax.jit(fn)
+        return jax.jit(fn, **jit_kwargs)
 
     return wrap
 
 
-def _to_pair(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Order-preserving int32 ``(hi, lo)`` image of float64 ``x``.
+def _key_words(h, l):
+    """Order-preserving int32 ``(hi, lo)`` pair of float64 IEEE words.
 
-    The int64 key is the IEEE bits with the magnitude bits flipped for
-    negative values (no NaN, no ``-0.0``); its high word and its low word
-    (offset so that signed order is unsigned order) compare
-    lexicographically as the floats do.
+    ``h`` and ``l`` are the high and low 32-bit words of the bits; the pair
+    is :func:`.ref._to_pair`'s, word for word.  A negative value (sign bit
+    set, ``s = -1``) has its magnitude bits flipped, and the low word is
+    offset by ``2**31`` so that signed order is unsigned order.  The map
+    keeps the sign bit, so it is its own inverse: applied to a pair it
+    gives back the IEEE words.
     """
-    bits = np.ascontiguousarray(x, np.float64).view(np.int64)
-    return _split(bits ^ ((bits >> 63) & _MAGNITUDE))
+    s = h >> 31
+    return h ^ (s & _MAGNITUDE), l ^ s ^ PAIR_MIN
 
 
-def _split(k: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """The int32 ``(hi, lo)`` words of int64 keys ``k``, in ``k``'s order."""
-    return (k >> 32).astype(np.int32), ((k & 0xFFFF_FFFF) - 2**31).astype(np.int32)
+def _words(bits):
+    """The high and low int32 words of int64 ``bits``, by shift and convert."""
+    return (bits >> 32).astype(jnp.int32), bits.astype(jnp.int32)
 
 
-def _from_pair(hi, lo) -> np.ndarray:
-    k = (np.asarray(hi).astype(np.int64) << 32) | (
-        np.asarray(lo).astype(np.int64) + 2**31
-    )
-    return (k ^ ((k >> 63) & _MAGNITUDE)).view(np.float64)
-
-
-@_named_jit("replay_cummax")
+@jax.jit
 def _cummax_lax(hi, lo):
     """Doubling-shift running max of ``(hi, lo)`` pairs along axis 1.
 
@@ -127,16 +136,35 @@ def _cummax_lax(hi, lo):
     compiles in about two seconds.
     """
     n, s = hi.shape[1], 1
-    with jax.named_scope("replay_cummax"):
-        while s < n:
-            fill = jnp.full((hi.shape[0], s), PAIR_MIN, jnp.int32)
-            hi, lo = lexmax(
-                hi, lo,
-                jnp.concatenate([fill, hi[:, :-s]], axis=1),
-                jnp.concatenate([fill, lo[:, :-s]], axis=1),
-            )
-            s *= 2
+    while s < n:
+        fill = jnp.full((hi.shape[0], s), PAIR_MIN, jnp.int32)
+        hi, lo = lexmax(
+            hi, lo,
+            jnp.concatenate([fill, hi[:, :-s]], axis=1),
+            jnp.concatenate([fill, lo[:, :-s]], axis=1),
+        )
+        s *= 2
     return hi, lo
+
+
+@_named_jit("replay_cummax", static_argnames=("scan", "chunk", "interpret"))
+def _replay_cummax(bits, scan, chunk, interpret):
+    """Row-wise running max of float64 values given as int64 IEEE bits.
+
+    Splits the bits into words, maps them to key pairs, takes the running
+    max (the Pallas kernel for ``scan="pallas"``, else :func:`_cummax_lax`)
+    and maps the result back to the IEEE high and low words.  They return
+    as two int32 arrays: an int64 result comes back from a v5e several
+    times slower, and interleaving the words on the device would build a
+    minor dimension of 2, which XLA pads to 128 lanes.
+    """
+    with jax.named_scope("replay_cummax"):
+        hi, lo = _key_words(*_words(bits))
+        if scan == "pallas":
+            hi, lo = cummax_2d(hi, lo, chunk=chunk, interpret=interpret)
+        else:
+            hi, lo = _cummax_lax(hi, lo)
+        return _key_words(hi, lo)
 
 
 def _lt(a, b):
@@ -208,7 +236,7 @@ def _prefix_sum(x, axis):
     return x
 
 
-@_named_jit("replay_search")
+@jax.jit
 def _merge_rank(a_hi, a_lo, q_hi, q_lo):
     """Row-wise ``searchsorted(a, q, side="left")`` over ``(hi, lo)`` pairs.
 
@@ -237,45 +265,71 @@ def _merge_rank(a_hi, a_lo, q_hi, q_lo):
     def row(a, q):
         return jnp.concatenate([a, q[:, ::-1]], axis=1).reshape(R, m // LANES, LANES)
 
+    words = [row(a_hi, q_hi), row(a_lo, q_lo),
+             row(jnp.ones_like(a_hi), jnp.zeros_like(q_hi))]
+    stages, h = [], n
+    while h:
+        swap = _swaps(words, h)
+        words = [_exchange(w, h, swap) for w in words]
+        stages.append((h, swap))
+        h //= 2
+    within = _prefix_sum(words[2], 2)  # along each 128-lane row
+    before = _prefix_sum(within[:, :, -1], 1) - within[:, :, -1]
+    count = within + before[:, :, None]
+    for h, swap in reversed(stages):
+        count = _exchange(count, h, swap)
+    return count.reshape(R, m)[:, n:][:, ::-1]
+
+
+@_named_jit("replay_search")
+def _replay_search(a_bits, q_bits):
+    """:func:`_merge_rank` of float64 rows given as int64 IEEE bits."""
     with jax.named_scope("replay_search"):
-        words = [row(a_hi, q_hi), row(a_lo, q_lo),
-                 row(jnp.ones_like(a_hi), jnp.zeros_like(q_hi))]
-        stages, h = [], n
-        while h:
-            swap = _swaps(words, h)
-            words = [_exchange(w, h, swap) for w in words]
-            stages.append((h, swap))
-            h //= 2
-        within = _prefix_sum(words[2], 2)  # along each 128-lane row
-        before = _prefix_sum(within[:, :, -1], 1) - within[:, :, -1]
-        count = within + before[:, :, None]
-        for h, swap in reversed(stages):
-            count = _exchange(count, h, swap)
-        return count.reshape(R, m)[:, n:][:, ::-1]
+        return _merge_rank(*_key_words(*_words(a_bits)),
+                           *_key_words(*_words(q_bits)))
 
 
-def _device_cummax(hi, lo, scan, chunk, interpret):
-    """Row-wise running max of int32 ``(hi, lo)`` pairs on the device.
+def _bits(x: np.ndarray) -> np.ndarray:
+    """The IEEE bits of float64 ``x``: a zero-copy ``int64`` view."""
+    return np.ascontiguousarray(x, np.float64).view(np.int64)
 
-    The device never orders a 64-bit value: the v5e emulates 64-bit
-    integers, and XLA's running max over int64 replay keys disagreed with
-    numpy there, while the same max over int32 pairs agreed.  Blocks until
-    the numpy result is on the host.
+
+def _floats(h: np.ndarray, l: np.ndarray) -> np.ndarray:
+    """float64 values from their IEEE high and low int32 words: one
+    interleaving copy (the words of a little-endian float64 lie low first)."""
+    w = np.empty(h.shape + (2,), np.int32)
+    w[..., 0], w[..., 1] = l, h
+    return w.view(np.float64)[..., 0]
+
+
+def _device_cummax(x, scan, chunk, interpret) -> np.ndarray:
+    """Row-wise running max of float64 ``x`` on the device, shipped as bits.
+
+    Bitwise ``np.maximum.accumulate``; blocks until the result is on the
+    host.  The device orders int32 pairs only: XLA's running max over int64
+    replay keys disagreed with numpy on a v5e, while the same max over int32
+    pairs agreed.
     """
-    with obs.span("device/cummax"):
-        hi, lo = jnp.asarray(hi), jnp.asarray(lo)
-        if scan == "pallas":
-            hi, lo = cummax_2d(hi, lo, chunk=chunk, interpret=interpret)
-        else:
-            hi, lo = _cummax_lax(hi, lo)
-        return np.asarray(hi), np.asarray(lo)
+    bits = _bits(x)
+    with obs.span("device/cummax"), x64():
+        h, l = (np.asarray(w) for w in _replay_cummax(
+            jax.device_put(bits), scan=scan, chunk=chunk, interpret=interpret))
+    obs.count("replay/bytes_to_device", bits.nbytes)
+    obs.count("replay/bytes_from_device", h.nbytes + l.nbytes)
+    with obs.span("decode"):
+        return _floats(h, l)
 
 
-def _device_search(a_hi, a_lo, q_hi, q_lo) -> np.ndarray:
-    """:func:`_merge_rank` on the device; blocks for the numpy result."""
-    with obs.span("device/search"):
-        pairs = [jnp.asarray(w) for w in (a_hi, a_lo, q_hi, q_lo)]
-        return np.asarray(_merge_rank(*pairs))
+def _device_search(a, q) -> np.ndarray:
+    """:func:`_replay_search` of float64 rows ``a`` and ``q``, shipped as
+    bits; blocks for the numpy result."""
+    a_bits, q_bits = _bits(a), _bits(q)
+    with obs.span("device/search"), x64():
+        idx = np.asarray(_replay_search(jax.device_put(a_bits),
+                                        jax.device_put(q_bits)))
+    obs.count("replay/bytes_to_device", a_bits.nbytes + q_bits.nbytes)
+    obs.count("replay/bytes_from_device", idx.nbytes)
+    return idx
 
 
 def cummax(
@@ -288,11 +342,7 @@ def cummax(
     """Row-wise running max of a 2D array, bitwise ``np.maximum.accumulate``."""
     if interpret is None:
         interpret = _auto_interpret()
-    with obs.span("encode"):
-        hi, lo = _to_pair(x)
-    hi, lo = _device_cummax(hi, lo, scan, chunk, interpret)
-    with obs.span("decode"):
-        return _from_pair(hi, lo)
+    return _device_cummax(x, scan, chunk, interpret)
 
 
 def _next_pow2(n: int, floor: int = 4096) -> int:
@@ -364,11 +414,10 @@ def replay_scan(
             )
     with obs.span("encode"):
         off = seg_id * big[:, None]
-        hi, lo = _to_pair(v + off)
-    hi, lo = _device_cummax(hi, lo, scan, chunk, interpret)
+        key = v + off
+    key_max = _device_cummax(key, scan, chunk, interpret)
     with obs.span("decode"):
-        running_max = _from_pair(hi, lo) - off
-        finish = s_local + running_max
+        finish = s_local + (key_max - off)
         start = finish - svc
         wait = start - t_s
         fmax = np.maximum(finish.max(axis=1), t_s.max(axis=1))
@@ -376,8 +425,8 @@ def replay_scan(
         big2 = (fmax - fmin) + 1.0
     with obs.span("encode"):
         off2 = seg_id * big2[:, None]
-        pairs = (*_to_pair(finish + off2), *_to_pair(t_s + off2))
-    idx = _device_search(*pairs)
+        a, q = finish + off2, t_s + off2
+    idx = _device_search(a, q)
     with obs.span("decode"):
-        depth = np.arange(npad) - idx.astype(np.int64)
+        depth = np.arange(npad) - idx
         return finish[:, :n], start[:, :n], wait[:, :n], depth[:, :n]

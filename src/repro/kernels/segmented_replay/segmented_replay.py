@@ -9,9 +9,10 @@ chunks)`` with the chunk axis innermost and sequential, a VMEM carry holding
 the inter-chunk running max, and a log2(Q) doubling-shift max-scan (lane
 rolls) inside each chunk.
 
-It scans integers, not floats.  The caller maps each float64 to an
-order-preserving int32 ``hi``/``lo`` pair (``ops._to_pair``; Mosaic takes
-32-bit operands), and the kernel takes the lexicographic max of the pairs.
+It scans integers, not floats.  The calling program maps each float64's
+IEEE words to an order-preserving int32 ``hi``/``lo`` pair
+(``ops._key_words``; Mosaic takes 32-bit operands), and the kernel takes
+the lexicographic max of the pairs.
 The comparisons are exact, so the output is bit-identical to
 ``np.maximum.accumulate`` for any chunk size — float64 on the TPU is
 emulated and not IEEE-exact, so a float scan there would not be.

@@ -245,8 +245,37 @@ def test_sweep_spans_and_counters_leave_rows_bit_identical(backend,
         width = pad // R
         assert width >= 4096 and width & (width - 1) == 0
         assert width == _next_pow2(real // R)
-    assert snap["counters"] == {"replay/lanes": sum(lanes),
-                                "replay/lanes_padded": sum(padded)}
+    assert snap["counters"] == {
+        "replay/lanes": sum(lanes), "replay/lanes_padded": sum(padded),
+        "replay/bytes_to_device": _BYTES_TO_DEVICE * sum(padded),
+        "replay/bytes_from_device": _BYTES_FROM_DEVICE * sum(padded)}
+
+
+# Per padded lane of the batched device replay: the running max ships one
+# key's float64 bits in and out; the depth search ships two keys in and
+# returns one int32 insertion point.
+_BYTES_TO_DEVICE = 8 + 2 * 8
+_BYTES_FROM_DEVICE = 8 + 4
+
+
+def test_replay_byte_counters_follow_padded_lanes():
+    """``replay/bytes_*`` are the per-lane bytes times ``replay/lanes_padded``
+    for one sweep, and are absent while ``repro.obs`` is off."""
+    pytest.importorskip("jax")
+    from repro.serve import ServingGridSpec, sweep_serving_grid
+
+    grid = ServingGridSpec(qps=(400.0,), capacities_mb=(32.0,),
+                           technologies=("sot_opt", "sram"), model="gpt2",
+                           serving=_SERVE_CFG, engine=_ENGINE_CFG)
+    sweep_serving_grid(grid, backend="jax")
+    obs.enable()
+    assert obs.counters() == {}
+    sweep_serving_grid(grid, backend="jax")
+    counters = obs.counters()
+    padded = counters["replay/lanes_padded"]
+    assert padded > 0
+    assert counters["replay/bytes_to_device"] == _BYTES_TO_DEVICE * padded
+    assert counters["replay/bytes_from_device"] == _BYTES_FROM_DEVICE * padded
 
 
 def test_sweep_spans_land_on_the_profilers_host_line(tmp_path):

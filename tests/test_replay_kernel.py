@@ -232,13 +232,18 @@ def test_cummax_matches_numpy(chunk):
 
 
 @needs_jax
-def test_replay_scan_padding_is_neutral():
-    """Pow-2 padding must not perturb any real output, bitwise."""
+@pytest.mark.parametrize("t0", [0.0, -1e9])
+def test_replay_scan_padding_is_neutral(t0):
+    """Pow-2 padding must not perturb any real output, bitwise.
+
+    ``t0 = -1e9`` puts every issue time, and so every key the device
+    orders, below zero: the word map's negative branch.
+    """
     from repro.kernels.segmented_replay.ops import replay_scan
 
     rng = np.random.default_rng(11)
     R, n = 2, 5000  # pads to 8192 (> the 4096 floor)
-    t = np.sort(rng.uniform(0, 1e5, (R, n)), axis=1)
+    t = t0 + np.sort(rng.uniform(0, 1e5, (R, n)), axis=1)
     svc = rng.uniform(1, 50, (R, n))
     seg_id = np.sort(rng.integers(0, 40, (R, n)), axis=1).astype(np.float64)
     cs = np.cumsum(svc, axis=1)
@@ -254,6 +259,93 @@ def test_replay_scan_padding_is_neutral():
         for name, a, b in zip(("finish", "start", "wait", "depth"), ref, got):
             assert b.shape == (R, n)
             np.testing.assert_array_equal(a, b, err_msg=f"{scan}/{name}")
+
+
+WORD_CASES = ("random_bits", "subnormal", "max_finite", "inf", "equal_hi",
+              "pad_tail")
+
+
+def _word_case(case, R, n, rng):
+    """float64 ``(R, n)`` values for the key-word map: no NaN, no ``-0.0``."""
+    if case == "random_bits":  # every IEEE pattern of both signs
+        bits = rng.integers(-(2**63), 2**63, (R, n), dtype=np.int64)
+    elif case == "subnormal":  # magnitudes below the smallest normal
+        bits = rng.integers(1, 2**52, (R, n), dtype=np.int64)
+        bits[:, ::7] = 2**52  # the smallest normal
+    elif case == "equal_hi":  # one high word per sign, every low word
+        bits = (np.int64(0x4237_4876) << 32) | rng.integers(0, 2**32, (R, n))
+    else:
+        bits = None
+    if bits is not None:
+        x = bits.view(np.float64)
+        if case != "random_bits":
+            x = np.where(rng.random((R, n)) < 0.5, -x, x)
+    elif case == "max_finite":
+        top = np.finfo(np.float64).max
+        x = rng.choice([top, np.nextafter(top, 0), 1.0, 0.0, -1.0,
+                        -np.nextafter(top, 0), -top], (R, n))
+    elif case == "inf":
+        x = rng.normal(0.0, 1e11, (R, n))
+        x[rng.random((R, n)) < 0.2] = np.inf
+        x[rng.random((R, n)) < 0.2] = -np.inf
+    elif case == "pad_tail":  # keys of _pad_neutral's tail segment
+        from repro.kernels.segmented_replay.ops import _pad_neutral
+
+        real = n - n // 3
+        t = np.sort(rng.uniform(0, 1e5, (R, real)), axis=1)
+        seg = np.sort(rng.integers(0, 20, (R, real)), axis=1).astype(np.float64)
+        svc = rng.uniform(1, 50, (R, real))
+        v, seg, _, _, _ = _pad_neutral(t - svc, seg, svc, svc, t, n)
+        x = v + seg * ((v.max(axis=1) - v.min(axis=1)) + 1.0)[:, None]
+    else:
+        raise AssertionError(case)
+    bad = np.isnan(x) | ((x == 0) & np.signbit(x))
+    return np.where(bad, 1.0, x)
+
+
+@needs_jax
+@pytest.mark.parametrize("case", WORD_CASES)
+def test_key_word_map_matches_reference(case):
+    """The device's key-word map is ``ref._to_pair`` / ``ref._from_pair``.
+
+    The map alone, jitted, word for word; then the two program entries that
+    wrap it: a running max of unsorted rows, one of sorted rows (which must
+    give the input bits back), and the depth search of sorted rows.
+    """
+    import jax
+
+    from repro.device import x64
+    from repro.kernels.segmented_replay.ops import (
+        _floats,
+        _key_words,
+        _replay_cummax,
+        _replay_search,
+        _words,
+    )
+    from repro.kernels.segmented_replay.ref import _from_pair, _to_pair
+
+    R, n = 3, 4096
+    x = _word_case(case, R, n, np.random.default_rng(WORD_CASES.index(case)))
+    bits = x.view(np.int64)
+    hi, lo = _to_pair(x)
+    with x64():
+        got_hi, got_lo = jax.jit(lambda b: _key_words(*_words(b)))(bits)
+        np.testing.assert_array_equal(got_hi, hi)
+        np.testing.assert_array_equal(got_lo, lo)
+        back = _floats(*jax.jit(_key_words)(hi, lo)).view(np.int64)
+        np.testing.assert_array_equal(back, _from_pair(hi, lo).view(np.int64))
+        np.testing.assert_array_equal(back, bits)
+
+        srt = np.sort(x, axis=1)
+        for rows, want in ((x, np.maximum.accumulate(x, axis=1)), (srt, srt)):
+            got = _floats(*_replay_cummax(rows.view(np.int64), scan="lax",
+                                          chunk=1024, interpret=True))
+            np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+        q = np.sort(np.random.default_rng(1).permutation(x.ravel())[:R * n]
+                    .reshape(R, n), axis=1)
+        got = _replay_search(srt.view(np.int64), q.view(np.int64))
+    want = np.stack([np.searchsorted(srt[r], q[r], side="left") for r in range(R)])
+    np.testing.assert_array_equal(got, want)
 
 
 MERGE_CASES = ("ties", "long_runs", "equal_hi", "extreme_words",
@@ -292,7 +384,8 @@ def _merge_rank_case(case, R, n, rng):
 
 
 def _merge_rank_check(a, q):
-    from repro.kernels.segmented_replay.ops import _merge_rank, _split, _to_pair
+    from repro.kernels.segmented_replay.ops import _merge_rank
+    from repro.kernels.segmented_replay.ref import _split, _to_pair
 
     words = _to_pair if a.dtype == np.float64 else _split
     got = np.asarray(_merge_rank(*words(a), *words(q)))

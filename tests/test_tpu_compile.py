@@ -3,9 +3,10 @@
 Nothing runs: each test lowers and compiles one device program for a v5e
 chip that is described, not attached — the serving sweep's replay at its
 real shape (5 technologies x 2^20 events) and the jitted DSE grid at the
-paper grid's shapes.  What the chip's compiler would refuse — an unaligned
-block, a 64-bit Mosaic operand, a lowering that takes minutes — fails here
-at no chip time.
+paper grid's shapes; the replay programs take their float64 keys as int64
+IEEE bits and split them into int32 words inside.  What the chip's
+compiler would refuse — an unaligned block, a 64-bit Mosaic operand, a
+lowering that takes minutes — fails here at no chip time.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.  All
@@ -31,7 +32,6 @@ from repro.dse.access import entity_size_grid  # noqa: E402
 from repro.dse.geomgrid import _design_points, _geom_ppa_fields  # noqa: E402
 from repro.dse.grid import PPAGrid, _compute_time_grid, _jitted_eval  # noqa: E402
 from repro.kernels.segmented_replay import ops  # noqa: E402
-from repro.kernels.segmented_replay.segmented_replay import cummax_2d  # noqa: E402
 
 R, N = 5, 1 << 20
 
@@ -73,18 +73,23 @@ def _spec(one_chip, dtype):
 
 
 def test_replay_running_max_compiles(one_chip):
-    x = _spec(one_chip, jnp.int32)
-    ops._cummax_lax.lower(x, x).compile()
+    with x64():
+        x = _spec(one_chip, jnp.int64)
+        ops._replay_cummax.lower(x, scan="lax", chunk=ops.DEFAULT_CHUNK,
+                                 interpret=False).compile()
 
 
 def test_replay_depth_searchsorted_compiles(one_chip):
-    x = _spec(one_chip, jnp.int32)
-    ops._merge_rank.lower(x, x, x, x).compile()
+    with x64():
+        x = _spec(one_chip, jnp.int64)
+        ops._replay_search.lower(x, x).compile()
 
 
 def test_pallas_cummax_compiles_to_mosaic(one_chip):
-    x = _spec(one_chip, jnp.int32)
-    compiled = cummax_2d.lower(x, x, chunk=ops.DEFAULT_CHUNK, interpret=False).compile()
+    with x64():
+        x = _spec(one_chip, jnp.int64)
+        compiled = ops._replay_cummax.lower(
+            x, scan="pallas", chunk=ops.DEFAULT_CHUNK, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
