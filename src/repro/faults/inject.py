@@ -1,8 +1,8 @@
 """Deterministic fault injection for the serving pricers and the fleet.
 
 Two mechanisms, deliberately split so nothing is double-counted and every
-pricing path (``TechPricer.price_step``, ``TechPricer.price_run``,
-``NeutralRun.price``) stays operand-identical:
+pricing path (``TechPricer.price_step``, ``NeutralRun.price``) stays
+operand-identical:
 
 1. **Expectation-level derating** (:func:`derate_system`): the *always-on*
    reliability machinery — the write-verify read after every write, the

@@ -2,10 +2,10 @@
 
 Every fault draw is a *pure function* of ``(seed, stream, index)`` — there
 is no sequential generator state to thread through the pricing paths, so
-the scalar closed loop, the batched ``price_run`` pass, and the sweep's
-:class:`~repro.serve.replay.NeutralRun` pricing all reproduce the same
+the serving loop's per-step ``TechPricer.price_step`` and the sweep's
+batched :meth:`~repro.serve.replay.NeutralRun.price` reproduce the same
 draws as long as they agree on the per-event index (they do: the
-within-class global event index is identical across all three paths, see
+within-class global event index is identical across both paths, see
 ``docs/faults.md``).  The hash is a splitmix64-style finalizer over the
 mixed counter words; the top 53 bits become a float64 uniform in [0, 1).
 """

@@ -1,29 +1,30 @@
 """Continuous-batching LLM serving engine on the SOT-MRAM memory system.
 
-Closes the loop between request arrivals and the bank-level simulator:
-an iteration-level continuous-batching scheduler (``scheduler``) runs over
-a paged KV-cache allocator that maps fixed-size KV pages onto GLB banks and
-spills cold pages to DRAM (``kv_pages``, a struct-of-arrays page table);
-the lowering (``lower``) emits one technology-neutral event block per
-traffic class per step (``BlockEmitter``; ``ScalarEmitter`` is the
-bit-identical per-request reference), prices them per GLB technology
-(``TechPricer``) through ``repro.sim``'s TraceBuilder, and scores the FIFO
-replay — TTFT/TPOT p50/p99, bank-conflict rate, GLB page residency.  The
-sweep engine (``sweep``) evaluates QPS x capacity x technology grids off
-one shared request draw: the lowered blocks are gathered once into a
-technology-neutral column run (``replay.NeutralRun``), priced vectorially
-per technology, and every certified technology's replay is scored in one
-batched segmented scan (``replay.score_shared_batch``, numpy/jax/pallas
-backends, bit-identical reports); points whose schedule-invariance
-certificate fails fall back to the per-point closed loop.
-``repro.dse.serving`` uses it to find the SLO-knee capacity.  The fleet
-layer (``fleet``) scales the loop to N replicas behind a pluggable router
-(round-robin / least-loaded / prefix-affinity), with optional
-prefill/decode disaggregation (KV-page streaming priced as a cross-replica
-traffic class) and a TTFT-SLO autoscaler; one replica slice per resource
-range keeps fleet pricing a single segmented-bincount pass, and the
-1-replica fleet is bit-identical to the closed loop.  See docs/serving.md
-and docs/perf.md.
+Closes the loop between request arrivals and the bank-level simulator.
+There is one serving loop, the event loop of ``fleet``: N replicas behind a
+pluggable router (round-robin / least-loaded / prefix-affinity), with
+optional prefill/decode disaggregation (KV-page streaming priced as a
+cross-replica traffic class) and a TTFT-SLO autoscaler.  A single
+accelerator is the 1-replica fleet: ``closed_loop_serving`` runs it with
+``FleetConfig()``.  Each replica steps an iteration-level continuous-batching
+scheduler (``scheduler``) over a paged KV-cache allocator that maps
+fixed-size KV pages onto GLB banks and spills cold pages to DRAM
+(``kv_pages``, a struct-of-arrays page table); the lowering (``lower``)
+emits one technology-neutral event block per traffic class per step
+(``BlockEmitter``; ``ScalarEmitter`` is the bit-identical per-request
+reference), prices them per GLB technology (``TechPricer``) through
+``repro.sim``'s TraceBuilder, and scores the FIFO replay — TTFT/TPOT
+p50/p99, bank-conflict rate, GLB page residency.  One replica slice per
+resource range keeps pricing a single segmented-bincount pass.  The sweep
+engine (``sweep``) evaluates QPS x capacity x technology grids off one
+shared request draw: one loop run per (qps, capacity) under the
+technology-invariant clock is gathered once into a technology-neutral
+column run (``replay.NeutralRun``), priced vectorially per technology, and
+every certified technology's replay is scored in one batched segmented
+scan (``replay.score_shared_batch``, numpy/jax/pallas backends,
+bit-identical reports); technologies whose schedule-invariance certificate
+fails fall back to their own loop.  ``repro.dse.serving`` uses it to find
+the SLO-knee capacity.  See docs/serving.md and docs/perf.md.
 """
 
 from repro.serve.fleet import (
@@ -31,6 +32,7 @@ from repro.serve.fleet import (
     FleetConfig,
     FleetReport,
     UnknownRouterPolicyError,
+    closed_loop_serving,
     fleet_serving,
     summarize_fleet,
 )
@@ -42,7 +44,6 @@ from repro.serve.lower import (
     ServeReport,
     StepBlocks,
     TechPricer,
-    closed_loop_serving,
     summarize_report,
 )
 from repro.serve.replay import (

@@ -1,11 +1,14 @@
-"""Fleet-scale serving: N replicas, routing, disaggregation, autoscaling.
+"""The serving loop: N replicas, routing, disaggregation, autoscaling.
 
-The closed loop in ``repro.serve.lower`` models **one** accelerator.  This
-module scales it to a *fleet*: ``n_replicas`` replicas, each with its own
-GLB capacity, paged-KV allocator, and bank queues, fed by a front-end
-router with pluggable policies and (optionally) split into prefill and
-decode pools with cross-replica KV streaming, plus a QPS-driven autoscaler
-that adds/drains replicas against the TTFT SLO.
+This module holds the one serving loop of the repository.  A *fleet* is
+``n_replicas`` replicas, each with its own GLB capacity, paged-KV allocator,
+and bank queues, fed by a front-end router with pluggable policies and
+(optionally) split into prefill and decode pools with cross-replica KV
+streaming, plus a QPS-driven autoscaler that adds/drains replicas against
+the TTFT SLO.  A single accelerator is the 1-replica fleet with every knob
+off (``FleetConfig()``): :func:`closed_loop_serving` and the sweep's
+single-accelerator points run exactly this loop.  The lowering, pricing and
+scoring it calls live in :mod:`repro.serve.lower`.
 
 Design invariants:
 
@@ -19,10 +22,11 @@ Design invariants:
   delivered, and replicas stepped in global-time order (arrival routing
   wins ties), which guarantees that when a replica plans a step at time
   ``t`` every arrival ``<= t`` destined for it has already been routed.
-  With one replica that reduces *exactly* to ``drive_serving_loop``'s
-  clock: the 1-replica fleet is **bit-identical** to the single-accelerator
-  closed loop (golden-pinned by ``tests/test_fleet.py``) — that equivalence
-  is the refactor's safety net, and it extends the sweep's
+  With one replica that is the single-accelerator closed loop: one
+  scheduler stepping from its first arrival, idling to the next arrival
+  when it has no work.  Its outputs are golden-pinned to literal digests
+  (``tests/test_fleet.py``, ``tests/test_serving_golden.py``).  Event
+  order depends only on step durations, which extends the sweep's
   schedule-invariance certificate to fleets.
 * **Disaggregation as a traffic class.**  A disaggregated request runs as
   two scheduler halves: a prefill-half (``decode=0``) on a prefill replica
@@ -109,10 +113,9 @@ class UnknownRouterPolicyError(ValueError, KeyError):
 class FleetConfig:
     """Knobs of the replica fleet (router, disaggregation, autoscaler).
 
-    The default is a 1-replica fleet with every knob off — the
-    configuration under which the fleet loop is bit-identical to the
-    single-accelerator closed loop (and what a pre-fleet scenario JSON
-    without a ``fleet`` block resolves to).
+    The default is a 1-replica fleet with every knob off — the single
+    accelerator of :func:`closed_loop_serving` (and what a pre-fleet
+    scenario JSON without a ``fleet`` block resolves to).
     """
 
     n_replicas: int = 1
@@ -171,7 +174,11 @@ class FleetConfig:
 
     @property
     def trivial(self) -> bool:
-        """True when the fleet degenerates to the single-accelerator loop."""
+        """True for a single accelerator: one replica, every knob off.
+
+        It changes only what the outputs carry — no fleet trace meta, no
+        ``SweepRow.fleet``, no fleet timeline tracks — never the code path.
+        """
         return (self.n_replicas == 1 and not self.disaggregation
                 and not self.autoscale)
 
@@ -328,6 +335,9 @@ class Fleet:
             raise ValueError(f"unknown lowering {lowering!r}")
         self.lowering = lowering
         self.recorder = recorder
+        # Fleet timeline tracks (replica spans, alive and backlog counters);
+        # a single accelerator records the plain per-step timeline instead.
+        self._fleet_rec = None if fleet_cfg.trivial else recorder
         self.capacity = fleet_cfg.capacity_replicas
 
         self.replicas: list[_Replica] = []
@@ -388,9 +398,9 @@ class Fleet:
         self._sample_alive(t_ns)
 
     def _sample_alive(self, t_ns: float) -> None:
-        if self.recorder is not None and hasattr(self.recorder, "counter"):
-            self.recorder.counter("alive_replicas", t_ns,
-                                  float(self._alive_count()))
+        if self._fleet_rec is not None:
+            self._fleet_rec.counter("alive_replicas", t_ns,
+                                    float(self._alive_count()))
 
     def _alive_count(self) -> int:
         return sum(1 for r in self.replicas if r.alive)
@@ -426,9 +436,9 @@ class Fleet:
             target = self._pick(req.rid, self._pool("decode"))
             target.sched.add_request(req)
         target.routed += 1
-        if self.recorder is not None and hasattr(self.recorder, "counter"):
+        if self._fleet_rec is not None:
             backlog = sum(r.sched.backlog() for r in self.replicas if r.alive)
-            self.recorder.counter("router_backlog", req.arrival_ns, backlog)
+            self._fleet_rec.counter("router_backlog", req.arrival_ns, backlog)
 
     # -- disaggregated KV handoff ---------------------------------------------
     def _page_split_of(self, model: ServeModel, rid: int, n_tokens: int):
@@ -489,8 +499,7 @@ class Fleet:
         dst.routed += 1
         self.kv_xfer_transfers += 1
         self.kv_xfer_bytes += xfer_bytes
-        if self.recorder is not None and hasattr(self.recorder,
-                                                 "record_fleet_transfer"):
+        if self.recorder is not None:
             self.recorder.record_fleet_transfer(src_idx, dst.idx, ready,
                                                 xfer_bytes,
                                                 self.kv_xfer_bytes)
@@ -553,8 +562,7 @@ class Fleet:
                            (retry.arrival_ns, self._retry_seq, retry))
             self._retry_seq += 1
             self.requeued_requests += 1
-        if self.recorder is not None and hasattr(self.recorder,
-                                                 "record_fault"):
+        if self.recorder is not None:
             self.recorder.record_fault("replica_failure", t_ns, r.idx,
                                        len(lost))
 
@@ -622,10 +630,12 @@ class Fleet:
         self.blocks_list.append(blocks)
         self.dts.append(dt)
         self.stats.account(blocks, dt)
-        if self.recorder is not None and hasattr(self.recorder,
-                                                 "record_fleet_step"):
-            self.recorder.record_fleet_step(r.idx, now, t_end, plan, blocks,
-                                            r.model.alloc, finished)
+        if self._fleet_rec is not None:
+            self._fleet_rec.record_fleet_step(r.idx, now, t_end, plan, blocks,
+                                              r.model.alloc, finished)
+        elif self.recorder is not None:
+            self.recorder.record_step(now, t_end, plan, blocks, r.model.alloc,
+                                      finished)
         self.total_steps += 1
         if self.total_steps > _MAX_STEPS:  # pragma: no cover
             raise RuntimeError(f"fleet loop exceeded {_MAX_STEPS} steps")
@@ -639,8 +649,8 @@ class Fleet:
         Events are processed in global-time order with a fixed tie-break —
         arrival routing, then handoff delivery, then the earliest replica's
         step (autoscale checks slot in at their deadline ahead of any
-        later work) — so replica interleaving is deterministic and, for one
-        replica, reduces exactly to the monolithic closed loop.
+        later work) — so replica interleaving is deterministic; with one
+        replica this is the single-accelerator closed loop.
         """
         fc = self.fcfg
         self.step_time = step_time
@@ -758,6 +768,9 @@ class Fleet:
                 + self.prefail_tokens)
 
     def fleet_meta(self) -> dict:
+        """Trace-meta entries of the fleet axis; none for one accelerator."""
+        if self.fcfg.trivial:
+            return {}
         return {
             "n_replicas": self.fcfg.n_replicas,
             "capacity_replicas": self.capacity,
@@ -816,12 +829,12 @@ class Fleet:
         )
 
 
-def fleet_serving(
+def run_fleet(
     system: HybridMemorySystem,
     spec: NLPModelSpec,
-    cfg: ServingConfig = ServingConfig(),
-    engine_cfg: ServeEngineConfig = ServeEngineConfig(),
-    fleet_cfg: FleetConfig = FleetConfig(),
+    cfg: ServingConfig,
+    engine_cfg: ServeEngineConfig,
+    fleet_cfg: FleetConfig,
     sim_config: SimConfig | None = None,
     n_dram_channels: int = 8,
     n_prefetch_channels: int = 4,
@@ -830,29 +843,22 @@ def fleet_serving(
     recorder=None,
     faults: FaultConfig | None = None,
 ) -> tuple[Trace, FleetReport]:
-    """Run the closed-loop fleet to completion and score one fleet replay.
+    """Run the serving loop to completion and score one replay.
 
-    The exact-fleet analogue of
-    :func:`repro.serve.lower.closed_loop_serving`: every step's blocks are
-    priced against a fleet-wide :class:`TechPricer` (per-replica bank
-    slices in one resource space) and the priced busy times feed each
-    replica's clock.  With the default 1-replica :class:`FleetConfig` the
-    returned trace and report are **bit-identical** to
-    ``closed_loop_serving`` on the same inputs.
+    Every step's blocks are priced against a fleet-wide
+    :class:`TechPricer` (per-replica bank slices in one resource space) and
+    the priced busy times feed each replica's clock.  This is
+    :func:`fleet_serving` without its fault-free baseline rerun, so
+    ``ttft_p99_inflation`` stays 0; the sweep and
+    :func:`closed_loop_serving` call it directly.
 
-    ``faults`` arms the full campaign: reliability-derated pricing with
-    seeded write-retry/bank-offline injection (as in the closed loop) plus
-    replica failures — dead replicas drop their in-flight work, which is
-    requeued onto survivors after a capped exponential backoff, their lost
-    KV re-prefilled, while the router excludes them and the autoscaler (if
-    on) brings replacements up.  ``faults=None`` is bit-identical to today.
-
-    ``timing`` and the :mod:`repro.obs` spans ``loop`` and ``score`` split
-    the wall time as in ``closed_loop_serving``.
+    ``timing`` receives the ``loop_s`` (scheduler + allocator + lowering +
+    pricing) vs ``score_s`` (trace build + replay + report) wall-clock
+    split; with :mod:`repro.obs` enabled the same two phases are the spans
+    ``loop`` and ``score`` (``score/trace``, then the replay's own spans).
     """
     t_loop0 = time.perf_counter()
     with obs.span("loop"):
-        base_system = system
         if faults is not None:
             faults.validate()
             system = derate_system(system, faults)
@@ -882,14 +888,15 @@ def fleet_serving(
     t_score0 = time.perf_counter()
     with obs.span("score"):
         model0 = fleet.replicas[0].model
-        # A trivial (1-replica, knobs-off) fleet keeps the closed loop's
-        # exact metadata so the whole trace stays bit-identical.
-        extra = {} if fleet_cfg.trivial else fleet.fleet_meta()
+        fleet_extra = fleet.fleet_meta()
+        extra = dict(fleet_extra)
         if faults is not None:
             extra["faults"] = faults.to_dict()
             if pricer.fm is not None:
                 extra["fault_stats"] = pricer.fm.stats()
-            if faults.has_replica_faults:
+            # Replica failures are fleet entries too: a single accelerator
+            # cannot lose its only replica (``Fleet._can_fail``).
+            if fleet_extra and faults.has_replica_faults:
                 extra.update(fleet.fault_meta())
         with obs.span("trace"):
             trace = pricer.b.build(
@@ -899,49 +906,121 @@ def fleet_serving(
             )
         mean_alive = fleet.mean_alive()
         if mean_alive != 1.0:
-            # A fleet leaks on every alive chip; the 1-replica path skips
-            # the multiply so its leakage term stays bit-identical to the
-            # closed loop's.
+            # A fleet leaks on every alive chip; at 1.0 the multiply is
+            # skipped so the single chip's leakage term stays bit-exact.
             trace.leakage_w = system.glb.leakage_w * mean_alive
         sim_config = sim_config or SimConfig(
             coalesce_window_ns=4 * model0.interval_ns, kind_stats=False
         )
         report = score_requests(
             trace,
+            system=system,
+            sim_config=sim_config,
+            recorder=recorder,
             requests=fleet.logical,
             finished=fleet.finished_logical,
+            arrival_by_rid=fleet.arrival_by_rid,
             offered_qps=cfg.arrival_rate_rps,
             pages_spilled=fleet.pages_spilled(),
             pages_allocated=fleet.pages_allocated(),
             stats=fleet.stats,
-            system=system,
-            sim_config=sim_config,
-            arrival_by_rid=fleet.arrival_by_rid,
-            recorder=recorder,
         )
         fr = fleet.finalize(
             report, system,
-            fault_stats=(pricer.fm.stats() if (faults is not None
-                                               and pricer.fm is not None)
+            fault_stats=(pricer.fm.stats() if pricer.fm is not None
                          else None),
         )
-        if faults is not None and faults.baseline_inflation:
-            # One fault-free rerun anchors the degradation metric: how much
-            # the campaign inflated the tail TTFT over the same offered load.
-            _, base = fleet_serving(
-                base_system, spec, cfg, engine_cfg, fleet_cfg, sim_config,
-                n_dram_channels, n_prefetch_channels, lowering,
-            )
-            if base.report.ttft_p99_ms > 0:
-                fr.ttft_p99_inflation = (
-                    fr.report.ttft_p99_ms / base.report.ttft_p99_ms
-                )
     if timing is not None:
         timing["loop_s"] = timing.get("loop_s", 0.0) + (t_score0 - t_loop0)
         timing["score_s"] = (
             timing.get("score_s", 0.0) + time.perf_counter() - t_score0
         )
     return trace, fr
+
+
+def fleet_serving(
+    system: HybridMemorySystem,
+    spec: NLPModelSpec,
+    cfg: ServingConfig = ServingConfig(),
+    engine_cfg: ServeEngineConfig = ServeEngineConfig(),
+    fleet_cfg: FleetConfig = FleetConfig(),
+    sim_config: SimConfig | None = None,
+    n_dram_channels: int = 8,
+    n_prefetch_channels: int = 4,
+    lowering: str = "block",
+    timing: dict | None = None,
+    recorder=None,
+    faults: FaultConfig | None = None,
+) -> tuple[Trace, FleetReport]:
+    """Run the closed-loop fleet to completion and score one fleet replay.
+
+    See :func:`run_fleet` for the loop, ``timing`` and the spans.  With the
+    default 1-replica :class:`FleetConfig` the trace and report are those of
+    :func:`closed_loop_serving` on the same inputs.
+
+    ``faults`` arms the full campaign: reliability-derated pricing with
+    seeded write-retry/bank-offline injection (as on one accelerator) plus
+    replica failures — dead replicas drop their in-flight work, which is
+    requeued onto survivors after a capped exponential backoff, their lost
+    KV re-prefilled, while the router excludes them and the autoscaler (if
+    on) brings replacements up.  ``faults=None`` is bit-identical to today.
+    With ``faults.baseline_inflation`` one fault-free rerun of the same
+    fleet, after the faulted run and outside its ``timing``, sets
+    ``ttft_p99_inflation``.
+    """
+    trace, fr = run_fleet(system, spec, cfg, engine_cfg, fleet_cfg,
+                          sim_config, n_dram_channels, n_prefetch_channels,
+                          lowering, timing, recorder, faults)
+    if faults is not None and faults.baseline_inflation:
+        # The rerun anchors the degradation metric: how much the campaign
+        # inflated the tail TTFT over the same offered load.
+        _, base = run_fleet(system, spec, cfg, engine_cfg, fleet_cfg,
+                            sim_config, n_dram_channels, n_prefetch_channels,
+                            lowering)
+        if base.report.ttft_p99_ms > 0:
+            fr.ttft_p99_inflation = (
+                fr.report.ttft_p99_ms / base.report.ttft_p99_ms
+            )
+    return trace, fr
+
+
+def closed_loop_serving(
+    system: HybridMemorySystem,
+    spec: NLPModelSpec,
+    cfg: ServingConfig = ServingConfig(),
+    engine_cfg: ServeEngineConfig = ServeEngineConfig(),
+    sim_config: SimConfig | None = None,
+    n_dram_channels: int = 8,
+    n_prefetch_channels: int = 4,
+    lowering: str = "block",
+    timing: dict | None = None,
+    recorder=None,
+    faults: FaultConfig | None = None,
+) -> tuple[Trace, ServeReport]:
+    """Serve on one accelerator to completion and score the replay.
+
+    The single accelerator is the 1-replica fleet (:func:`run_fleet` with
+    ``FleetConfig()``).  ``lowering`` picks the step-lowering
+    implementation: ``"block"`` (the vectorized array program, default) or
+    ``"scalar"`` (the per-request reference loop — bit-identical output,
+    kept for equivalence testing and the ``benchmarks/serving_qps`` speedup
+    baseline).  ``timing`` and the spans are :func:`run_fleet`'s.
+    ``recorder`` (a :class:`repro.obs.TimelineRecorder`) taps the loop's
+    request lifecycles/counters and the replay's bank timeline for Perfetto
+    export; all recorder hooks are read-only, so the returned trace and
+    report are bit-identical with the recorder on or off.
+
+    ``faults`` arms deterministic fault injection: the GLB array is derated
+    for ECC/write-verify (expectation level), and the priced event stream
+    gains seeded write-retry accesses and bank-offline remap windows; the
+    trace meta records the campaign as given.  Replica failures never fire
+    on one accelerator.  The default ``None`` leaves the run bit-identical
+    to a fault-free build.
+    """
+    trace, fr = run_fleet(system, spec, cfg, engine_cfg, FleetConfig(),
+                          sim_config, n_dram_channels, n_prefetch_channels,
+                          lowering, timing, recorder, faults)
+    return trace, fr.report
 
 
 def summarize_fleet(fr: FleetReport) -> str:

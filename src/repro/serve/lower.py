@@ -1,13 +1,15 @@
-"""Closed-loop serving: drive the scheduler, lower to bank-level event blocks.
+"""Serving lowering, pricing and scoring: scheduler steps to a scored replay.
 
-``closed_loop_serving`` runs the continuous-batching scheduler step by step
-against the paged KV allocator, emits every step's memory traffic through
-the existing :class:`repro.sim.trace.TraceBuilder`, and feeds the *modelled*
-step duration (weight-stream cadence, per-bank GLB service, exposed DRAM
-spill time — whichever dominates) back into the clock.  Queueing therefore
-compounds: a step slowed by bank conflicts or KV spill delays every token
-behind it, which is exactly what the open-loop ``serving_trace`` cannot
-express.
+The serving loop itself is the event loop of :mod:`repro.serve.fleet`
+(``closed_loop_serving`` is its 1-replica case).  Each step it runs the
+continuous-batching scheduler against the paged KV allocator, lowers the
+step's memory traffic with an emitter from this module, prices it with a
+:class:`TechPricer` into the existing :class:`repro.sim.trace.TraceBuilder`,
+and feeds the *modelled* step duration (weight-stream cadence, per-bank GLB
+service, exposed DRAM spill time — whichever dominates) back into the clock.
+Queueing therefore compounds: a step slowed by bank conflicts or KV spill
+delays every token behind it, which is exactly what the open-loop
+``serving_trace`` cannot express.
 
 The lowering is an array program: each scheduler step emits one event
 *block* per traffic class (KV reads, KV appends, activations, spills,
@@ -36,24 +38,24 @@ Allocator transactions are step-batched: all of a step's page allocations
 run first (prefill then decode, in plan order, against the previous step's
 LRU stamps), then the decode touches commit as one vector store.
 
-The final event stream is scored by ``sim.engine``'s FIFO replay; per-token
-events are tagged with their request id so TTFT/TPOT p50/p99 are measured
-from *replayed* finish times (bank-accurate), not from the scheduler clock.
+The final event stream is scored by ``sim.engine``'s FIFO replay
+(:func:`score_requests`); per-token events are tagged with their request id
+so TTFT/TPOT p50/p99 are measured from *replayed* finish times
+(bank-accurate), not from the scheduler clock.  :func:`assemble_report`
+turns a replay into the :class:`ServeReport`, for this scorer and for the
+sweep's batched one alike.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
-import time
 
 import numpy as np
 
 from repro.core.access_counts import MemoryParams
 from repro.core.memory_system import HybridMemorySystem
 from repro.core.workload import NLPModelSpec
-from repro.faults import FaultConfig, derate_system, fault_model_for
-from repro.obs import core as obs
+from repro.faults import FaultConfig, fault_model_for
 from repro.sim.engine import SimConfig, SimResult, simulate_trace
 from repro.sim.trace import (
     KIND_DRAM_RD,
@@ -66,15 +68,10 @@ from repro.sim.trace import (
     Trace,
     TraceBuilder,
     _spec_weight_bytes,
-    draw_requests,
     trace_byte_counts,
 )
 from repro.serve.kv_pages import PagedKVAllocator
-from repro.serve.scheduler import (
-    ContinuousBatchScheduler,
-    ServeEngineConfig,
-    StepPlan,
-)
+from repro.serve.scheduler import ServeEngineConfig, StepPlan
 
 _MAX_STEPS = 200_000
 
@@ -519,8 +516,9 @@ class TechPricer:
     Applies the technology's bank count (``bank = hash % n_banks``), service
     latencies, and access energies, appends the events to a
     :class:`TraceBuilder`, and returns each step's (max per-bank GLB busy,
-    DRAM busy) for the closed-loop feedback and the sweep engine's
-    schedule-invariance certificate.
+    DRAM busy) for the serving loop's clock.  The sweep's shared path prices
+    a whole run at once instead (:meth:`repro.serve.replay.NeutralRun.price`,
+    same formulas).
 
     ``n_replicas`` widens the resource space to a fleet: every replica owns
     its own contiguous slice of GLB banks and DRAM/prefetch channels, and a
@@ -533,8 +531,8 @@ class TechPricer:
     injection: GLB writes gain seeded write-verify retry accesses and GLB
     banks struck by transient faults remap for one window — both drawn from
     the counter RNG keyed on the within-class event index / absolute time
-    window, so the streaming and batched paths inject identically.  ``None``
-    (the default) leaves every operand untouched.
+    window, so this per-step path and ``NeutralRun.price`` inject
+    identically.  ``None`` (the default) leaves every operand untouched.
     """
 
     def __init__(
@@ -572,22 +570,6 @@ class TechPricer:
         # reliability block — every injection branch below is then skipped,
         # keeping the zero-fault event stream bit-identical.
         self.fm = fault_model_for(system, faults, n_replicas=self.n_replicas)
-
-    @classmethod
-    def for_tech(
-        cls,
-        technology: str,
-        capacity_mb: float,
-        model: ServeModel,
-        n_dram_channels: int = 8,
-        n_prefetch_channels: int = 4,
-    ) -> "TechPricer":
-        """Registry-resolved pricer: the per-tech service/energy table comes
-        from ``repro.spec.get_tech(technology).build(capacity_mb)``."""
-        from repro.spec import build_system
-
-        return cls(build_system(technology, capacity_mb), model,
-                   n_dram_channels, n_prefetch_channels)
 
     def price_step(self, blk: StepBlocks) -> tuple[float, float]:
         """Emit one step's events; returns (max per-bank GLB ns, DRAM ns).
@@ -648,107 +630,6 @@ class TechPricer:
                   blk.pref_acc * self.e_dram_pj, KIND_PREFETCH_RD, n=ch.size)
         return glb_ns, dram_acc_total * self.t_dram_acc_ns
 
-    def price_run(self, blocks: list, dts: np.ndarray) -> bool:
-        """Price a whole shared-schedule run in one vectorized pass.
-
-        Concatenates every step's blocks per traffic class (event times
-        repeated per step), appends one event batch per class, and computes
-        the per-step per-bank GLB busy maxima with a single segmented
-        bincount.  Returns the schedule-invariance certificate: True iff no
-        step's GLB busy time exceeds its shared duration (the DRAM term is
-        already folded into ``dts``).
-
-        The replay outcome is identical to per-step pricing: steps have
-        strictly increasing start times, so a (resource, t_issue) tie group
-        never spans steps, and within one step reads still precede writes in
-        input order.  Only the *numbering* of fresh (never-coalesced) line
-        ids differs — invisible to coalescing and to every metric.
-        """
-        b, glb = self.b, self.system.glb
-        nb, S = self.nb, len(blocks)
-        nb_tot = self.nb_total
-        ts = np.fromiter((blk.t_ns for blk in blocks), np.float64, S)
-        reps = np.fromiter((blk.replica for blk in blocks), np.int64, S)
-        fleet = bool(reps.any())
-
-        def _gather(field):
-            parts = [getattr(blk, field) for blk in blocks]
-            sizes = np.fromiter((p.shape[0] for p in parts), np.int64, S)
-            return np.concatenate(parts), sizes
-
-        def _offset(local, sizes, per_replica):
-            # Replica-sliced resource ids; zero-cost on the 1-replica path.
-            if not fleet:
-                return local
-            return local + reps.repeat(sizes) * per_replica
-
-        # Certificate first: nothing touches the builder (or consumes fresh
-        # line ids) until the shared schedule is known to be exact for this
-        # technology, so an uncertified point wastes no event appends.
-        busy = np.zeros(S * nb_tot)
-        hash_rd, n_rd = _gather("glb_rd_hash")
-        svc_rd = acc_rd = bank_rd = None
-        if hash_rd.size:
-            acc_rd = np.concatenate([blk.glb_rd_acc for blk in blocks])
-            local_rd = hash_rd % nb
-            if self.fm is not None:
-                local_rd = self.fm.remap_banks(
-                    local_rd, ts.repeat(n_rd), reps.repeat(n_rd))
-            bank_rd = _offset(local_rd, n_rd, nb)
-            svc_rd = acc_rd * glb.read_latency_ns
-            busy += np.bincount(np.arange(S).repeat(n_rd) * nb_tot + bank_rd,
-                                weights=svc_rd, minlength=S * nb_tot)
-        hash_wr, n_wr = _gather("glb_wr_hash")
-        svc_wr = acc_wr = bank_wr = None
-        if hash_wr.size:
-            acc_wr = np.concatenate([blk.glb_wr_acc for blk in blocks])
-            local_wr = hash_wr % nb
-            if self.fm is not None:
-                # Batched injection must match the streaming path bit-for-bit:
-                # the retry draw is keyed on the within-class event index,
-                # which concatenation in block order preserves (offset 0).
-                local_wr = self.fm.remap_banks(
-                    local_wr, ts.repeat(n_wr), reps.repeat(n_wr))
-                acc_wr = self.fm.write_acc_at(acc_wr, 0)
-            bank_wr = _offset(local_wr, n_wr, nb)
-            svc_wr = acc_wr * glb.write_latency_ns
-            busy += np.bincount(np.arange(S).repeat(n_wr) * nb_tot + bank_wr,
-                                weights=svc_wr, minlength=S * nb_tot)
-        if not np.all(busy.reshape(S, nb_tot).max(axis=1) <= dts):
-            return False
-        if svc_rd is not None:
-            b.add(ts.repeat(n_rd), bank_rd, svc_rd,
-                  acc_rd * glb.read_energy_pj_per_access, KIND_GLB_RD)
-        if svc_wr is not None:
-            line = np.concatenate([blk.glb_wr_line for blk in blocks])
-            tag = np.concatenate([blk.glb_wr_tag for blk in blocks])
-            fresh = line < 0
-            if fresh.any():
-                line = line.copy()
-                line[fresh] = b.fresh_lines(int(fresh.sum()))
-            b.add(ts.repeat(n_wr), bank_wr, svc_wr,
-                  acc_wr * glb.write_energy_pj_per_access, KIND_GLB_WR,
-                  line=line, tag=tag)
-        for field_h, field_a, kind in (
-            ("dram_rd_hash", "dram_rd_acc", KIND_DRAM_RD),
-            ("dram_wr_hash", "dram_wr_acc", KIND_DRAM_WR),
-        ):
-            hashes, sizes = _gather(field_h)
-            if hashes.size:
-                acc = np.concatenate([getattr(blk, field_a) for blk in blocks])
-                ch = _offset((hashes % nb) % self.n_dram_ch, sizes,
-                             self.n_dram_ch)
-                b.add(ts.repeat(sizes), b.dram_resource(ch),
-                      acc * self.t_dram_acc_ch_ns, acc * self.e_dram_pj, kind)
-        chs, sizes = _gather("pref_ch")
-        if chs.size:
-            acc = np.concatenate([blk.pref_acc for blk in blocks])
-            ch = _offset(chs % self.n_pref_ch, sizes, self.n_pref_ch)
-            b.add(ts.repeat(sizes), b.prefetch_resource(ch),
-                  acc * self.t_dram_acc_ns * self.n_pref_ch,
-                  acc * self.e_dram_pj, KIND_PREFETCH_RD)
-        return True
-
 
 @dataclasses.dataclass
 class RunStats:
@@ -768,136 +649,12 @@ class RunStats:
         self.n_steps += 1
 
 
-def drive_serving_loop(sched: ContinuousBatchScheduler, emitter, step_time_fn,
-                       alloc: PagedKVAllocator, recorder=None):
-    """Run the scheduler to completion, yielding ``(blocks, dt)`` per step.
-
-    ``step_time_fn(blocks)`` maps one step's lowered blocks to its duration:
-    the closed loop prices the blocks and folds in the GLB/DRAM busy times;
-    the sweep engine's shared mode uses the technology-invariant terms alone.
-
-    ``recorder`` (a :class:`repro.obs.TimelineRecorder`) observes every
-    committed step — request lifecycle edges plus residency/spill counter
-    samples — without touching the clock, the allocator, or RNG state.
-    """
-    t = sched.next_arrival_ns()
-    n_steps = 0
-    while not sched.done:
-        plan = sched.plan_step(t)
-        if plan.empty:
-            nxt = sched.next_arrival_ns()
-            if not math.isfinite(nxt) or nxt <= t:  # pragma: no cover
-                raise RuntimeError("scheduler stalled with no admissible work")
-            t = nxt
-            continue
-        blocks = emitter.emit(plan)
-        dt = step_time_fn(blocks)
-        t_end = t + dt
-        finished = sched.commit_step(plan, t_end)
-        for r in finished:
-            alloc.free(r.rid)
-        if recorder is not None:
-            recorder.record_step(t, t_end, plan, blocks, alloc, finished)
-        t = t_end
-        n_steps += 1
-        if n_steps > _MAX_STEPS:  # pragma: no cover
-            raise RuntimeError(f"serving loop exceeded {_MAX_STEPS} steps")
-        yield blocks, dt
-
-
-def closed_loop_serving(
-    system: HybridMemorySystem,
-    spec: NLPModelSpec,
-    cfg: ServingConfig = ServingConfig(),
-    engine_cfg: ServeEngineConfig = ServeEngineConfig(),
-    sim_config: SimConfig | None = None,
-    n_dram_channels: int = 8,
-    n_prefetch_channels: int = 4,
-    lowering: str = "block",
-    timing: dict | None = None,
-    recorder=None,
-    faults: FaultConfig | None = None,
-) -> tuple[Trace, ServeReport]:
-    """Run the continuous-batching loop to completion and score the replay.
-
-    ``lowering`` picks the step-lowering implementation: ``"block"`` (the
-    vectorized array program, default) or ``"scalar"`` (the per-request
-    reference loop — bit-identical output, kept for equivalence testing and
-    the ``benchmarks/serving_qps`` speedup baseline).  Pass a dict as
-    ``timing`` to receive the ``loop_s`` (scheduler + allocator + lowering +
-    pricing) vs ``score_s`` (trace build + replay + report) wall-clock split;
-    with :mod:`repro.obs` enabled the same two phases are the spans ``loop``
-    and ``score`` (``score/trace``, then the replay's own spans).
-    ``recorder`` (a :class:`repro.obs.TimelineRecorder`) taps the loop's
-    request lifecycles/counters and the replay's bank timeline for Perfetto
-    export; all recorder hooks are read-only, so the returned trace and
-    report are bit-identical with the recorder on or off.
-
-    ``faults`` arms deterministic fault injection: the GLB array is derated
-    for ECC/write-verify (expectation level), and the priced event stream
-    gains seeded write-retry accesses and bank-offline remap windows.  The
-    default ``None`` leaves the run bit-identical to a fault-free build.
-    """
-    t_loop0 = time.perf_counter()
-    with obs.span("loop"):
-        if faults is not None:
-            faults.validate()
-            system = derate_system(system, faults)
-        rng = np.random.default_rng(cfg.seed)
-        arrivals, prompts, decodes = draw_requests(cfg, rng)
-        sched = ContinuousBatchScheduler(arrivals, prompts, decodes,
-                                         engine_cfg)
-        model = ServeModel(system, spec, cfg, engine_cfg)
-        if lowering == "block":
-            emitter = BlockEmitter(model)
-        elif lowering == "scalar":
-            emitter = ScalarEmitter(model)
-        else:
-            raise ValueError(f"unknown lowering {lowering!r}")
-        pricer = TechPricer(system, model, n_dram_channels,
-                            n_prefetch_channels, faults=faults)
-        stats = RunStats()
-
-        def step_time(blocks: StepBlocks) -> float:
-            glb_ns, dram_ns = pricer.price_step(blocks)
-            decode_ns = model.interval_ns if blocks.has_decode else 0.0
-            return max(decode_ns, blocks.prefill_ns, glb_ns, dram_ns)
-
-        for blocks, dt in drive_serving_loop(sched, emitter, step_time,
-                                             model.alloc, recorder=recorder):
-            stats.account(blocks, dt)
-    t_score0 = time.perf_counter()
-    with obs.span("score"):
-        fault_extra = {}
-        if faults is not None:
-            fault_extra = {"faults": faults.to_dict()}
-            if pricer.fm is not None:
-                fault_extra["fault_stats"] = pricer.fm.stats()
-        with obs.span("trace"):
-            trace = pricer.b.build(
-                compute_time_s=0.0,
-                meta=serving_run_meta(spec, cfg, engine_cfg, system, model,
-                                      stats, lowering, **fault_extra),
-            )
-        sim_config = sim_config or SimConfig(
-            coalesce_window_ns=4 * model.interval_ns, kind_stats=False
-        )
-        report = score_run(trace, sched, model, stats, system, sim_config,
-                           recorder=recorder)
-    if timing is not None:
-        timing["loop_s"] = timing.get("loop_s", 0.0) + (t_score0 - t_loop0)
-        timing["score_s"] = (
-            timing.get("score_s", 0.0) + time.perf_counter() - t_score0
-        )
-    return trace, report
-
-
 def serving_run_meta(spec: NLPModelSpec, cfg: ServingConfig,
                      engine_cfg: ServeEngineConfig,
                      system: HybridMemorySystem, model: ServeModel,
                      stats: RunStats, lowering: str, **extra) -> dict:
-    """Trace metadata of one serving run — single source for the closed loop
-    and the sweep engine's shared-schedule path."""
+    """Trace metadata of one serving run — single source for the fleet loop's
+    own trace and the sweep engine's shared-schedule path."""
     return {
         "scenario": "serving_closed_loop",
         "model": spec.name,
@@ -930,9 +687,9 @@ def replay_token_times(
 
     ``tags``/``finish_ns`` are parallel arrays over the replayed events
     (original tags gathered into replay order via ``orig_idx``; untagged
-    events carry ``-1``).  Shared by the closed loop, the batched
-    shared-schedule scorer, and the fleet — one implementation of the
-    tag -> lexsort -> group reduction.
+    events carry ``-1``).  Shared by :func:`score_requests` and the batched
+    shared-schedule scorer — one implementation of the tag -> lexsort ->
+    group reduction.
     """
     m = tags >= 0
     if not m.any():
@@ -953,39 +710,30 @@ def replay_token_times(
     return ttft, tpot
 
 
-def score_requests(
+def assemble_report(
+    result: SimResult,
+    ttft: np.ndarray,
+    tpot: np.ndarray,
     trace: Trace,
+    system: HybridMemorySystem,
     *,
     requests: list,
     finished: list,
+    arrival_by_rid: dict,
     offered_qps: float,
     pages_spilled: int,
     pages_allocated: int,
     stats: RunStats,
-    system: HybridMemorySystem,
-    sim_config: SimConfig,
-    arrival_by_rid: dict | None = None,
-    recorder=None,
 ) -> ServeReport:
-    """Replay a lowered serving trace and distill the :class:`ServeReport`.
+    """One run's :class:`ServeReport` from its replay and its requests.
 
-    Decoupled from the scheduler so the fleet can score its *logical*
-    request population (disaggregated requests live as two scheduler halves
-    but one logical request): ``requests``/``finished`` are
-    :class:`RequestState` lists and ``arrival_by_rid`` overrides the
-    per-request arrival used for TTFT (defaults to each finished request's
-    own ``arrival_ns`` — the single-scheduler case).
+    ``result`` and the per-request ``ttft``/``tpot`` samples come from the
+    replay of ``trace``.  The keywords describe the *logical* request
+    population (a disaggregated request runs as two scheduler halves but
+    counts once): ``requests``/``finished`` are :class:`RequestState` lists
+    and ``arrival_by_rid`` maps each request to its original arrival, from
+    which the scheduler-clock TTFT/TPOT and the serving span are taken.
     """
-    result, schedule, orig_idx = simulate_trace(trace, sim_config,
-                                                return_schedule=True,
-                                                recorder=recorder)
-
-    # Per-request token-completion times from the replay (tagged events).
-    if arrival_by_rid is None:
-        arrival_by_rid = {r.rid: r.arrival_ns for r in finished}
-    ttft, tpot = replay_token_times(trace.tag[orig_idx], schedule.finish_ns,
-                                    arrival_by_rid)
-
     sched_ttft = np.array(
         [r.first_token_ns - arrival_by_rid.get(r.rid, r.arrival_ns)
          for r in finished]
@@ -1036,28 +784,25 @@ def score_requests(
     )
 
 
-def score_run(
+def score_requests(
     trace: Trace,
-    sched: ContinuousBatchScheduler,
-    model: ServeModel,
-    stats: RunStats,
+    *,
     system: HybridMemorySystem,
     sim_config: SimConfig,
     recorder=None,
+    **logical,
 ) -> ServeReport:
-    """Single-scheduler scoring: the closed loop's thin wrapper."""
-    return score_requests(
-        trace,
-        requests=sched.requests,
-        finished=sched.finished,
-        offered_qps=model.cfg.arrival_rate_rps,
-        pages_spilled=model.alloc.spill_count,
-        pages_allocated=model.alloc.pages_created,
-        stats=stats,
-        system=system,
-        sim_config=sim_config,
-        recorder=recorder,
-    )
+    """Replay a lowered serving trace and distill the :class:`ServeReport`.
+
+    ``logical`` are :func:`assemble_report`'s request keywords.
+    """
+    result, schedule, orig_idx = simulate_trace(trace, sim_config,
+                                                return_schedule=True,
+                                                recorder=recorder)
+    # Per-request token-completion times from the replay (tagged events).
+    ttft, tpot = replay_token_times(trace.tag[orig_idx], schedule.finish_ns,
+                                    logical["arrival_by_rid"])
+    return assemble_report(result, ttft, tpot, trace, system, **logical)
 
 
 def summarize_report(r: ServeReport) -> str:

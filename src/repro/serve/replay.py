@@ -11,22 +11,20 @@ This module batches all of that:
 
 * :class:`NeutralRun` flattens one shared run's ``StepBlocks`` **once** into
   technology-neutral columns (issue times, kinds, coalescing lines, tags,
-  per-class hash/access arrays), laid out class-major in exactly
-  ``TechPricer.price_run``'s append order — GLB reads, GLB writes, DRAM
-  reads, DRAM writes, prefetch — with the fresh-line counter numbering
-  mirrored, so the columns are byte-for-byte the trace ``price_run`` would
-  have built.
+  per-class hash/access arrays), laid out class-major — GLB reads, GLB
+  writes, DRAM reads, DRAM writes, prefetch — with fresh-line ids numbered
+  from the same counter start as :class:`~repro.serve.lower.TechPricer`.
 * :meth:`NeutralRun.price` prices those columns for one concrete memory
   system: a handful of vectorized multiplies per class (``bank = hash %
-  n_banks``, service/energy scaled) plus the same schedule-invariance
-  certificate bincount, producing a :class:`TechPricing` whose
+  n_banks``, service/energy scaled) plus the schedule-invariance
+  certificate's segmented bincount, producing a :class:`TechPricing` whose
   resource/service/energy columns slot straight into a :class:`Trace` view.
 * :func:`score_shared_batch` replays **all** certified technologies in one
   :func:`repro.sim.engine.replay_schedule_batch` call — the write-combining
   mask is computed once (it depends only on the shared time/kind/line
   columns), the per-row scan runs through the numpy / ``jax.lax.cummax`` /
   Pallas backend, and each row is distilled into a :class:`ServeReport`
-  operand-for-operand like ``simulate_trace`` + ``score_run``.
+  operand-for-operand like ``simulate_trace`` + ``score_requests``.
 
 Bit-exactness is the contract, not an aspiration: every float operation
 (pricing multiplies, coalesced-energy sums, masked metric sums, percentile
@@ -62,16 +60,13 @@ from repro.sim.trace import (
     KIND_PREFETCH_RD,
     KIND_PREFETCH_WR,
     Trace,
-    trace_byte_counts,
 )
 from repro.serve.lower import (
     ServeModel,
     ServeReport,
-    RunStats,
-    _percentiles_ms,
+    assemble_report,
     replay_token_times,
     score_requests,
-    score_run,
 )
 
 _CLASSES = ("glb_rd", "glb_wr", "dram_rd", "dram_wr", "pref")
@@ -99,10 +94,10 @@ class TechPricing:
 class NeutralRun:
     """Technology-neutral flattening of one shared-schedule serving run.
 
-    Columns are class-major in ``TechPricer.price_run``'s exact append order;
-    the shared ``line`` column reproduces its fresh-line numbering (counter
-    starts past the reserved KV-append namespace, then advances through GLB
-    reads, fresh GLB writes, DRAM reads, DRAM writes, prefetch).  Flattening
+    Columns are class-major (GLB reads, GLB writes, DRAM reads, DRAM writes,
+    prefetch); the shared ``line`` column numbers fresh lines from a counter
+    that starts past the reserved KV-append namespace and advances through
+    the classes in that order.  Flattening
     happens once per (qps, capacity); every technology prices the same
     columns.
     """
@@ -188,10 +183,10 @@ class NeutralRun:
             self.kind[sl] = kind_c
         self.tag[self.sl["glb_wr"]] = wr_tag
 
-        # Fresh-line numbering, mirrored from TechPricer: the counter starts
-        # past the reserved KV-append namespace and advances through each
-        # class's append in order (GLB writes consume ids only for their
-        # fresh, line < 0, events).
+        # Fresh-line numbering: as in TechPricer, the counter starts past the
+        # reserved KV-append namespace; it advances through each class in
+        # order (GLB writes consume ids only for their fresh, line < 0,
+        # events).
         line = np.empty(n, np.int64)
         c = model.cfg.n_requests * model.n_layers
         sl = self.sl["glb_rd"]
@@ -216,7 +211,7 @@ class NeutralRun:
         """Price the neutral columns for one memory system + certificate.
 
         Same formulas (and float operation order) as
-        ``TechPricer.price_step``/``price_run``: ``bank = hash % n_banks``,
+        ``TechPricer.price_step``: ``bank = hash % n_banks``,
         service/energy scaled by the technology's latency/energy table, DRAM
         channels folded from the bank hash, prefetch channels shared.
 
@@ -253,9 +248,9 @@ class NeutralRun:
             bank_rd = bank_rd + self.rep_rd * nb
             bank_wr = bank_wr + self.rep_wr * nb
 
-        # Schedule-invariance certificate (same segmented bincount as
-        # ``price_run``): no step's per-bank GLB busy may exceed the shared
-        # step duration.  Fleet transfer blocks carry ``inf`` durations in
+        # Schedule-invariance certificate (one segmented bincount over every
+        # step): no step's per-bank GLB busy may exceed the shared step
+        # duration.  Fleet transfer blocks carry ``inf`` durations in
         # ``dts`` — they never pace the clock, so they cannot decertify.
         busy = np.zeros(self.S * nb_tot)
         if bank_rd.size:
@@ -420,18 +415,9 @@ def _distill_row(
 def score_shared_batch(
     traces: list,
     systems: list,
-    sched,
-    model: ServeModel,
-    stats: RunStats,
     sim_config: SimConfig,
     recorder=None,
-    *,
-    requests: list | None = None,
-    finished: list | None = None,
-    arrival_by_rid: dict | None = None,
-    offered_qps: float | None = None,
-    pages_spilled: int | None = None,
-    pages_allocated: int | None = None,
+    **logical,
 ) -> list[ServeReport]:
     """Score N technology-priced traces of one shared run in one replay.
 
@@ -439,39 +425,23 @@ def score_shared_batch(
     (they come from one :class:`NeutralRun`), so the write-combining mask is
     computed once; the per-technology resource/service columns are stacked
     into a single :func:`replay_schedule_batch` call, and each row distilled
-    into a :class:`ServeReport` bit-identical to ``score_run`` on that trace
-    alone.  ``systems`` pairs each trace with the memory system that priced
-    it.  ``recorder`` taps the first trace's replay (matching the sweep's
-    first-grid-point recording contract).
-
-    The keyword overrides decouple the scorer from a single scheduler, the
-    same way :func:`repro.serve.lower.score_requests` does — the fleet sweep
-    passes its logical request population (and original-arrival map) while
-    ``sched``/``model`` default the single-accelerator case.
+    into a :class:`ServeReport` bit-identical to
+    :func:`~repro.serve.lower.score_requests` on that trace alone.
+    ``systems`` pairs each trace with the memory system that priced it.
+    ``recorder`` taps the first trace's replay (matching the sweep's
+    first-grid-point recording contract).  ``logical`` are
+    :func:`~repro.serve.lower.assemble_report`'s request keywords, the run's
+    logical request population.
     """
     if not traces:
         return []
-    if requests is None:
-        requests = sched.requests
-    if finished is None:
-        finished = sched.finished
-    if offered_qps is None:
-        offered_qps = model.cfg.arrival_rate_rps
-    if pages_spilled is None:
-        pages_spilled = model.alloc.spill_count
-    if pages_allocated is None:
-        pages_allocated = model.alloc.pages_created
     t0 = traces[0]
     n_total = len(t0)
     if n_total == 0:
         return [
-            score_requests(tr, requests=requests, finished=finished,
-                           offered_qps=offered_qps,
-                           pages_spilled=pages_spilled,
-                           pages_allocated=pages_allocated,
-                           stats=stats, system=system, sim_config=sim_config,
-                           arrival_by_rid=arrival_by_rid,
-                           recorder=(recorder if i == 0 else None))
+            score_requests(tr, system=system, sim_config=sim_config,
+                           recorder=(recorder if i == 0 else None),
+                           **logical)
             for i, (tr, system) in enumerate(zip(traces, systems))
         ]
 
@@ -496,73 +466,18 @@ def score_shared_batch(
         recorder.record_replay(batch.row(0), t0)
 
     with obs.span("distill"):
-        # Scheduler-clock metrics are shared by every technology on the grid.
-        if arrival_by_rid is None:
-            arrival_by_rid = {req.rid: req.arrival_ns for req in finished}
-        sched_ttft = np.array(
-            [req.first_token_ns - arrival_by_rid.get(req.rid, req.arrival_ns)
-             for req in finished]
-        )
-        sched_tpot = np.array(
-            [
-                (req.finish_ns - req.first_token_ns) / (req.decoded - 1)
-                for req in finished
-                if req.decoded > 1
-            ]
-        )
-        finishes = [req.finish_ns for req in finished]
-        arrivals = [arrival_by_rid.get(req.rid, req.arrival_ns)
-                    for req in requests]
-        span_ns = (max(finishes) - min(arrivals)) if finishes else 0.0
-        kv_rd_total = stats.kv_rd_bytes_glb + stats.kv_rd_bytes_dram
-
         reports = []
         for r, (trace, system) in enumerate(zip(traces, systems)):
             energy_k = trace.energy_pj[kept]
             coalesced_e = float(trace.energy_pj[dropped].sum())
             result = _distill_row(batch, r, trace, kind_k, energy_k, n_total,
                                   int(dropped.size), coalesced_e, sim_config)
-
             # Per-request token completions from the replay's tagged events,
-            # exactly as in ``score_run``.
+            # exactly as in ``score_requests``.
             orig_idx = kept[batch.order[r]]
             ttft, tpot = replay_token_times(trace.tag[orig_idx],
-                                            batch.finish_ns[r], arrival_by_rid)
-
-            ttft_p50, ttft_p99 = _percentiles_ms(ttft)
-            tpot_p50, tpot_p99 = _percentiles_ms(tpot)
-            reports.append(ServeReport(
-                n_requests=len(requests),
-                completed=len(finished),
-                n_steps=stats.n_steps,
-                offered_qps=offered_qps,
-                achieved_qps=(
-                    len(finished) / (span_ns * 1e-9) if span_ns else 0.0
-                ),
-                span_s=span_ns * 1e-9,
-                ttft_p50_ms=ttft_p50,
-                ttft_p99_ms=ttft_p99,
-                tpot_p50_ms=tpot_p50,
-                tpot_p99_ms=tpot_p99,
-                sched_ttft_p99_ms=(
-                    float(np.percentile(sched_ttft, 99)) * 1e-6
-                    if sched_ttft.size else 0.0
-                ),
-                sched_tpot_p99_ms=(
-                    float(np.percentile(sched_tpot, 99)) * 1e-6
-                    if sched_tpot.size else 0.0
-                ),
-                residency_mean=(
-                    stats.residency_wsum / stats.dt_sum if stats.dt_sum else 1.0
-                ),
-                pages_spilled=pages_spilled,
-                pages_allocated=pages_allocated,
-                kv_spill_read_frac=(
-                    stats.kv_rd_bytes_dram / kv_rd_total if kv_rd_total else 0.0
-                ),
-                bank_conflict_rate=result.bank_conflict_rate,
-                mean_queue_depth=result.mean_queue_depth,
-                bytes=trace_byte_counts(trace, system),
-                sim=result,
-            ))
+                                            batch.finish_ns[r],
+                                            logical["arrival_by_rid"])
+            reports.append(assemble_report(result, ttft, tpot, trace, system,
+                                           **logical))
     return reports

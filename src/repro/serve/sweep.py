@@ -1,8 +1,12 @@
-"""Shared-grid QPS x capacity x technology sweep over the serving closed loop.
+"""Shared-grid QPS x capacity x technology sweep over the serving loop.
 
-Evaluating a serving design grid point by point re-runs the scheduler, the
-page allocator, and the lowering for every (qps, capacity, technology)
-triple, even though most of that work is identical across the grid:
+Every grid point runs the one serving loop, the fleet event loop of
+:mod:`repro.serve.fleet`; a single-accelerator grid is the 1-replica fleet
+(``ServingGridSpec.fleet`` left at ``FleetConfig()``), whose rows carry no
+``fleet`` extras.  Evaluating a serving design grid point by point re-runs
+the scheduler, the page allocator, and the lowering for every (qps,
+capacity, technology) triple, even though most of that work is identical
+across the grid:
 
 * the **request population** is load-invariant up to a scale factor —
   NumPy's ``Generator.exponential(scale)`` is exactly ``scale *
@@ -21,13 +25,12 @@ allocator + block lowering **once**, with
 :class:`~repro.serve.lower.StepBlocks` per technology (bank = hash %
 n_banks, service/energy scaled).  While pricing it checks the *exactness
 certificate*: if every step's priced per-bank GLB busy time stays within
-the shared step duration, the full closed loop with that technology would
-have produced byte-for-byte the same schedule, so the shared result is
-exact — not an approximation.  A
-technology that violates the certificate (congestion would have stretched
-its steps) falls back to its own closed loop, so ``sweep_serving_grid``
-always returns closed-loop-exact rows; ``shared`` on each row records which
-path produced it.
+the shared step duration, the loop run with that technology's own clock
+would have produced byte-for-byte the same schedule, so the shared result
+is exact — not an approximation.  A technology that violates the
+certificate (congestion would have stretched its steps) falls back to its
+own loop, so ``sweep_serving_grid`` always returns exact rows; ``shared``
+on each row records which path produced it.
 
 Scoring is batched: per (qps, capacity) the shared run's step blocks are
 flattened **once** into technology-neutral trace columns
@@ -38,9 +41,10 @@ write-combining mask, the time sort, and the segmented max-plus scan are
 shared or batched instead of recomputed per technology.  ``backend`` picks
 the scan implementation: ``"numpy"`` (``np.maximum.accumulate``), ``"jax"``
 (one fused jitted XLA program around ``jax.lax.cummax``), ``"pallas"`` (the
-chunked ``repro.kernels.segmented_replay`` kernel), or ``"auto"`` (jax when
-importable, else numpy).  All backends produce bit-identical rows — pinned
-by ``tests/test_replay_kernel.py``.
+chunked ``repro.kernels.segmented_replay`` kernel, which ``"auto"`` never
+picks), or ``"auto"`` (jax when JAX runs on an accelerator, numpy on a CPU
+or without JAX — :func:`repro.sim.engine.resolve_backend`).  All backends
+produce bit-identical rows — pinned by ``tests/test_replay_kernel.py``.
 """
 
 from __future__ import annotations
@@ -56,19 +60,10 @@ from repro.obs import core as obs
 from repro.sim.engine import SimConfig, resolve_backend
 from repro.sim.trace import ServingConfig, arrivals_at_qps, draw_request_shape
 from repro.spec import build_system, tech_group
-from repro.serve.lower import (
-    BlockEmitter,
-    RunStats,
-    ScalarEmitter,
-    ServeModel,
-    ServeReport,
-    closed_loop_serving,
-    drive_serving_loop,
-    serving_run_meta,
-)
-from repro.serve.fleet import Fleet, FleetConfig, FleetReport, fleet_serving
+from repro.serve.lower import ServeModel, ServeReport, serving_run_meta
+from repro.serve.fleet import Fleet, FleetConfig, FleetReport, run_fleet
 from repro.serve.replay import NeutralRun, score_shared_batch
-from repro.serve.scheduler import ContinuousBatchScheduler, ServeEngineConfig
+from repro.serve.scheduler import ServeEngineConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,8 +77,7 @@ class ServingGridSpec:
     serving: ServingConfig = ServingConfig()
     engine: ServeEngineConfig = ServeEngineConfig()
     # Fleet axis: replicas/router/disaggregation/autoscaler.  The default
-    # (1 replica, knobs off) routes through the original single-accelerator
-    # shared path bit-identically.
+    # (1 replica, knobs off) is a single accelerator.
     fleet: FleetConfig = FleetConfig()
     # Fault axis: a FaultConfig makes every row *iso-reliability* — each
     # technology is priced on its reliability-derated twin (MRAM pays
@@ -115,7 +109,7 @@ class ServingGridSpec:
 
 @dataclasses.dataclass
 class SweepRow:
-    """One grid point's closed-loop-exact outcome."""
+    """One grid point's exact outcome."""
 
     technology: str
     capacity_mb: float
@@ -125,40 +119,6 @@ class SweepRow:
     # Fleet-mode extras (None on single-accelerator grids): the full
     # FleetReport wrapping ``report``, with cost-per-token and replica axes.
     fleet: FleetReport | None = None
-
-
-def _shared_run(model: ServeModel, sched: ContinuousBatchScheduler,
-                lowering: str, t_dram_acc_ns: float, recorder=None):
-    """Drive the loop once with the technology-invariant clock.
-
-    The step feedback's DRAM term is ``total accesses x access time`` — no
-    per-channel max — so it is identical for every technology and can be
-    folded into the shared clock exactly.  Only the per-bank GLB busy time
-    is technology-dependent; it is what the certificate checks per tech.
-    ``recorder`` taps the shared loop's request lifecycles and residency
-    counters (read-only, no effect on the schedule).
-    """
-    emitter = (BlockEmitter if lowering == "block" else ScalarEmitter)(model)
-    stats = RunStats()
-    blocks_list, dts = [], []
-
-    def shared_dt(blocks):
-        decode_ns = model.interval_ns if blocks.has_decode else 0.0
-        # Same accumulation order as TechPricer.price_step, so the value is
-        # bit-identical to the closed loop's dram_ns term.
-        dram_acc = 0.0
-        if blocks.dram_rd_acc.size:
-            dram_acc += float(blocks.dram_rd_acc.sum())
-        if blocks.dram_wr_acc.size:
-            dram_acc += float(blocks.dram_wr_acc.sum())
-        return max(decode_ns, blocks.prefill_ns, dram_acc * t_dram_acc_ns)
-
-    for blocks, dt in drive_serving_loop(sched, emitter, shared_dt,
-                                         model.alloc, recorder=recorder):
-        stats.account(blocks, dt)
-        blocks_list.append(blocks)
-        dts.append(dt)
-    return blocks_list, np.asarray(dts), stats
 
 
 def sweep_serving_grid(
@@ -175,8 +135,9 @@ def sweep_serving_grid(
 
     ``mode="shared"`` (default) reuses one schedule per (qps, capacity)
     across technologies with the exactness certificate + per-technology
-    closed-loop fallback; ``mode="exact"`` runs every triple through its own
-    closed loop (the reference path the certificate is validated against).
+    fallback to the technology's own loop; ``mode="exact"`` runs every
+    triple through its own loop (the reference path the certificate is
+    validated against).
 
     ``backend`` selects the replay-scan implementation (``"auto"`` picks
     jax on an accelerator and numpy on CPU — see
@@ -212,173 +173,21 @@ def sweep_serving_grid(
 
     rows: list[SweepRow] = []
     rec_pending = recorder  # consumed by the first grid point
-    fleet_mode = not spec.fleet.trivial
     with obs.span("sweep"):
         for cap in spec.capacities_mb:
             for qps in spec.qps:
                 cfg = dataclasses.replace(spec.serving, arrival_rate_rps=qps)
                 rec, rec_pending = rec_pending, None
-                if fleet_mode:
-                    rows.extend(_fleet_grid_point(
-                        spec, nlp, cfg, cap, qps, mode, backend,
-                        interarrival_std, prompts, decodes,
-                        n_dram_channels, n_prefetch_channels, lowering,
-                        timing, rec,
-                    ))
-                elif mode == "exact":
-                    rows.extend(_exact_grid_point(
-                        spec, nlp, cfg, cap, qps, backend,
-                        n_dram_channels, n_prefetch_channels, lowering,
-                        timing, rec,
-                    ))
-                else:
-                    rows.extend(_shared_grid_point(
-                        spec, nlp, cfg, cap, qps, backend,
-                        interarrival_std, prompts, decodes,
-                        n_dram_channels, n_prefetch_channels, lowering,
-                        timing, rec,
-                    ))
+                rows.extend(_grid_point(
+                    spec, nlp, cfg, cap, qps, mode, backend,
+                    interarrival_std, prompts, decodes,
+                    n_dram_channels, n_prefetch_channels, lowering,
+                    timing, rec,
+                ))
     return rows
 
 
-def _exact_grid_point(spec, nlp, cfg, cap, qps, backend, n_dram_channels,
-                      n_prefetch_channels, lowering, timing, rec
-                      ) -> list[SweepRow]:
-    """One (capacity, qps) point, every technology on its own closed loop."""
-    out = []
-    for tech in spec.technologies:
-        system = build_system(tech, cap)
-        # sim_config=None reproduces the closed loop's own default
-        # (4x-cadence coalescing, no kind stats); only a non-default replay
-        # backend needs an explicit config.
-        _, rep = closed_loop_serving(
-            system, nlp, cfg, spec.engine,
-            sim_config=(None if backend == "numpy" else
-                        _sim_config(system, nlp, cfg, spec.engine, backend)),
-            n_dram_channels=n_dram_channels,
-            n_prefetch_channels=n_prefetch_channels,
-            lowering=lowering,
-            timing=timing,
-            recorder=rec,
-            faults=spec.faults,
-        )
-        rec = None
-        out.append(SweepRow(tech, cap, qps, False, rep))
-    return out
-
-
-def _shared_grid_point(spec, nlp, cfg, cap, qps, backend, interarrival_std,
-                       prompts, decodes, n_dram_channels, n_prefetch_channels,
-                       lowering, timing, rec) -> list[SweepRow]:
-    """One (capacity, qps) point off one shared schedule, all technologies.
-
-    Spans (:mod:`repro.obs`): ``loop`` (``schedule``: scheduler, allocator,
-    lowering; ``price``: neutral columns and per-technology pricing) and
-    ``score`` (``trace``, then :func:`score_shared_batch`), at the same
-    boundaries as ``timing``; ``fallback`` around each uncertified
-    technology's own closed loop.
-    """
-    # One scheduler + allocator + lowering pass per (qps, capacity).
-    t0 = time.perf_counter()
-    with obs.span("loop"):
-        with obs.span("schedule"):
-            arrivals = arrivals_at_qps(interarrival_std, qps)
-            ref_system = build_system(spec.technologies[0], cap)
-            dram = ref_system.dram  # shared by every technology on the grid
-            t_dram_acc_ns = (
-                dram.access_bytes / (dram.bandwidth_gb_s * 1e9) * 1e9
-            )
-            model = ServeModel(ref_system, nlp, cfg, spec.engine)
-            sched = ContinuousBatchScheduler(arrivals, prompts, decodes,
-                                             spec.engine)
-            blocks_list, dts, stats = _shared_run(model, sched, lowering,
-                                                  t_dram_acc_ns, recorder=rec)
-        with obs.span("price"):
-            # Flatten the run's blocks once (class-major neutral columns),
-            # then price every technology off the same columns.  The
-            # shared clock already carries the (tech-invariant) DRAM busy
-            # term; only the per-bank GLB busy time can push a technology
-            # off the shared schedule — the pricing certificate checks
-            # every step.
-            run = NeutralRun(blocks_list, dts, model,
-                             n_dram_channels, n_prefetch_channels)
-            # Iso-reliability pricing: each technology prices its derated
-            # twin with its own fault model (a fresh model per tech — the
-            # retry stream restarts at offset 0 exactly as the exact loop's
-            # does), so certified shared rows stay bitwise equal to exact.
-            tech_systems = [
-                derate_system(build_system(tech, cap), spec.faults)
-                for tech in spec.technologies
-            ]
-            pricings = [
-                run.price(system, fault_model_for(system, spec.faults))
-                for system in tech_systems
-            ]
-    timing["loop_s"] += time.perf_counter() - t0
-    sim_config = SimConfig(
-        coalesce_window_ns=4 * model.interval_ns, backend=backend,
-        kind_stats=False,
-    )
-
-    # All certified technologies replay in one batched pass.
-    t0 = time.perf_counter()
-    with obs.span("score"):
-        certified = [(tech, p) for tech, p in
-                     zip(spec.technologies, pricings) if p.certified]
-        shared_reports: dict[str, ServeReport] = {}
-        if certified:
-            with obs.span("trace"):
-                traces = [
-                    run.build_trace(p, serving_run_meta(
-                        nlp, cfg, spec.engine, p.system, model, stats,
-                        lowering, schedule="shared"))
-                    for _, p in certified
-                ]
-            reports = score_shared_batch(
-                traces, [p.system for _, p in certified], sched, model,
-                stats, sim_config,
-                # The recorder taps the first technology's replay only when
-                # that technology is certified (first certified trace ==
-                # first technology then).
-                recorder=(rec if pricings[0].certified else None),
-            )
-            shared_reports = {
-                tech: rep for (tech, _), rep in zip(certified, reports)
-            }
-    timing["score_s"] += time.perf_counter() - t0
-
-    out = []
-    for tech, pricing in zip(spec.technologies, pricings):
-        if pricing.certified:
-            out.append(SweepRow(tech, cap, qps, True, shared_reports[tech]))
-            continue
-        # Congestion would have stretched this technology's steps: replay
-        # its own closed loop (still block-lowered).  The shared loop
-        # already recorded this grid point's lifecycles, so the fallback
-        # only taps the replay.  The closed loop derates the base system
-        # itself, so it gets the registry build, not the already-derated
-        # pricing system.
-        with obs.span("fallback"):
-            _, rep = closed_loop_serving(
-                build_system(tech, cap), nlp, cfg, spec.engine,
-                sim_config=sim_config,
-                n_dram_channels=n_dram_channels,
-                n_prefetch_channels=n_prefetch_channels,
-                lowering=lowering,
-                timing=timing,
-                faults=spec.faults,
-            )
-        out.append(SweepRow(tech, cap, qps, False, rep))
-    return out
-
-
-def _sim_config(system, nlp, cfg, engine, backend) -> SimConfig:
-    model = ServeModel(system, nlp, cfg, engine)
-    return SimConfig(coalesce_window_ns=4 * model.interval_ns, backend=backend,
-                     kind_stats=False)
-
-
-def _fleet_grid_point(
+def _grid_point(
     spec: ServingGridSpec,
     nlp: NLPModelSpec,
     cfg: ServingConfig,
@@ -395,72 +204,82 @@ def _fleet_grid_point(
     timing: dict,
     rec,
 ) -> list[SweepRow]:
-    """One (capacity, qps) point of a *fleet* grid, all technologies.
+    """One (capacity, qps) point of the grid, all technologies.
 
-    The shared-schedule argument extends to fleets unchanged: router
-    decisions (backlog counts), handoff delivery times, and autoscale
-    actions (sched-clock TTFT p99) are all functions of the step durations,
-    and the shared clock's terms (decode cadence, prefill time, DRAM busy)
-    are technology-invariant.  So one fleet run under the shared clock fixes
-    the entire event interleaving, and the per-step per-bank certificate —
-    now over the replica-sliced resource space, with transfer blocks
-    carrying ``+inf`` step budgets — proves per technology that the exact
-    fleet would have produced byte-for-byte the same schedule.  Certified
-    technologies replay in one batch; violators fall back to their own
-    exact fleet loop.
+    ``mode="exact"`` runs each technology's own fleet loop
+    (:func:`~repro.serve.fleet.run_fleet`).  Shared mode runs one fleet
+    loop under the technology-invariant clock: router decisions (backlog
+    counts), handoff delivery times, and autoscale actions (sched-clock
+    TTFT p99) are all functions of the step durations, and the shared
+    clock's terms (decode cadence, prefill time, DRAM busy) are
+    technology-invariant.  So one run fixes the entire event interleaving,
+    and the per-step per-bank certificate — over the replica-sliced
+    resource space, with transfer blocks carrying ``+inf`` step budgets —
+    proves per technology that the exact loop would have produced
+    byte-for-byte the same schedule.  Certified technologies replay in one
+    batch; violators fall back to their own exact loop.
 
-    One caveat the single-accelerator grid does not have: when two replicas
-    step at the *same* timestamp, the exact fleet appends their events
-    step-major while the shared path gathers them class-major.  Per-resource
-    order is unchanged (replicas own disjoint resource slices), so every
-    replayed metric — TTFT/TPOT, finish times, queue depths — is still
+    Spans (:mod:`repro.obs`): ``loop`` (``schedule``: scheduler, allocator,
+    lowering; ``price``: neutral columns and per-technology pricing) and
+    ``score`` (``trace``, then :func:`score_shared_batch`), at the same
+    boundaries as ``timing``; ``fallback`` around each uncertified
+    technology's own loop.
+
+    The exact loop appends events step-major while the shared path gathers
+    them class-major.  Per-resource order is unchanged (within a step reads
+    precede writes either way, and replicas own disjoint resource slices),
+    so every replayed metric — TTFT/TPOT, finish times, queue depths — is
     bitwise identical; only whole-trace float reductions (aggregate energy,
-    byte totals) may differ in the last ulp between the certified-shared row
-    and a hand-run exact fleet.
+    byte totals) may differ in the last ulp between a certified-shared row
+    and the exact loop's.
     """
-    # Sweep rows never pay the fault-free baseline rerun (the grid itself
-    # carries the fault-free comparison point: run it with faults=None).
-    faults = (dataclasses.replace(spec.faults, baseline_inflation=False)
-              if spec.faults is not None else None)
-    if mode == "exact":
-        out = []
-        for tech in spec.technologies:
-            system = build_system(tech, cap)
-            _, fr = fleet_serving(
-                system, nlp, cfg, spec.engine, spec.fleet,
-                sim_config=(None if backend == "numpy" else
-                            _sim_config(system, nlp, cfg, spec.engine,
-                                        backend)),
-                n_dram_channels=n_dram_channels,
-                n_prefetch_channels=n_prefetch_channels,
-                lowering=lowering, timing=timing, recorder=rec,
-                faults=faults,
-            )
-            rec = None
-            out.append(SweepRow(tech, cap, qps, False, fr.report, fleet=fr))
-        return out
+    ref_system = build_system(spec.technologies[0], cap)
+    # The DRAM model, and with it the decode cadence, is shared by every
+    # technology on the grid.
+    sim_config = SimConfig(
+        coalesce_window_ns=(
+            4 * ServeModel(ref_system, nlp, cfg, spec.engine).interval_ns),
+        backend=backend, kind_stats=False,
+    )
 
-    # One fleet loop under the technology-invariant clock (replica failures
-    # strike at schedule-independent absolute times, so the shared
-    # interleaving carries the same outage/requeue sequence as the exact
-    # fleet whenever the certificate holds).
+    def own_loop(tech: str, recorder=None) -> FleetReport:
+        # The loop derates the registry build itself.  Rows never pay
+        # fleet_serving's fault-free baseline rerun: the grid carries the
+        # fault-free comparison point (run it with faults=None).
+        _, fr = run_fleet(
+            build_system(tech, cap), nlp, cfg, spec.engine, spec.fleet,
+            sim_config, n_dram_channels, n_prefetch_channels, lowering,
+            timing, recorder, spec.faults,
+        )
+        return fr
+
+    results: list[tuple[str, bool, FleetReport]] = []
+    if mode == "exact":
+        for tech in spec.technologies:
+            results.append((tech, False, own_loop(tech, rec)))
+            rec = None
+        return _rows(spec, cap, qps, results)
+
+    # Replica failures strike at schedule-independent absolute times, so the
+    # shared interleaving carries the same outage/requeue sequence as the
+    # exact fleet whenever the certificate holds.
     t0 = time.perf_counter()
     with obs.span("loop"):
         with obs.span("schedule"):
             arrivals = arrivals_at_qps(interarrival_std, qps)
-            ref_system = build_system(spec.technologies[0], cap)
-            dram = ref_system.dram  # shared by every technology on the grid
+            dram = ref_system.dram
             t_dram_acc_ns = (
                 dram.access_bytes / (dram.bandwidth_gb_s * 1e9) * 1e9
             )
             fleet = Fleet(ref_system, nlp, cfg, spec.engine, spec.fleet,
-                          lowering=lowering, recorder=rec, faults=faults)
+                          lowering=lowering, recorder=rec,
+                          faults=spec.faults)
 
             def shared_dt(replica, blocks):
                 decode_ns = (replica.model.interval_ns if blocks.has_decode
                              else 0.0)
                 # Same accumulation order as TechPricer.price_step, so the
-                # value is bit-identical to the exact fleet's dram_ns term.
+                # value is bit-identical to the exact loop's dram_ns term.
                 dram_acc = 0.0
                 if blocks.dram_rd_acc.size:
                     dram_acc += float(blocks.dram_rd_acc.sum())
@@ -475,24 +294,27 @@ def _fleet_grid_point(
             run = NeutralRun(fleet.blocks_list, fleet.dts_array, model0,
                              n_dram_channels, n_prefetch_channels,
                              n_replicas=fleet.capacity)
-            tech_systems = [derate_system(build_system(tech, cap), faults)
-                            for tech in spec.technologies]
-            fms = [fault_model_for(system, faults, n_replicas=fleet.capacity)
+            # Iso-reliability pricing: each technology prices its derated
+            # twin with its own fault model (a fresh model per tech — the
+            # retry stream restarts at offset 0 exactly as the exact loop's
+            # does), so certified shared rows stay bitwise equal to exact.
+            tech_systems = [
+                derate_system(build_system(tech, cap), spec.faults)
+                for tech in spec.technologies
+            ]
+            fms = [fault_model_for(system, spec.faults,
+                                   n_replicas=fleet.capacity)
                    for system in tech_systems]
             pricings = [run.price(system, fm)
                         for system, fm in zip(tech_systems, fms)]
     timing["loop_s"] += time.perf_counter() - t0
-    sim_config = SimConfig(
-        coalesce_window_ns=4 * model0.interval_ns, backend=backend,
-        kind_stats=False,
-    )
 
     t0 = time.perf_counter()
     with obs.span("score"):
         mean_alive = fleet.mean_alive()
-        certified = [(tech, p) for tech, p in
-                     zip(spec.technologies, pricings) if p.certified]
-        shared_fleet: dict[str, FleetReport] = {}
+        certified = [(tech, p, fm) for tech, p, fm in
+                     zip(spec.technologies, pricings, fms) if p.certified]
+        shared: dict[str, FleetReport] = {}
         if certified:
             with obs.span("trace"):
                 traces = [
@@ -500,11 +322,13 @@ def _fleet_grid_point(
                         nlp, cfg, spec.engine, p.system, model0, fleet.stats,
                         lowering, schedule="shared", **fleet.fleet_meta()),
                         leakage_scale=mean_alive)
-                    for _, p in certified
+                    for _, p, _ in certified
                 ]
             reports = score_shared_batch(
-                traces, [p.system for _, p in certified], None, None,
-                fleet.stats, sim_config,
+                traces, [p.system for _, p, _ in certified], sim_config,
+                # The recorder taps the first technology's replay only when
+                # that technology is certified (first certified trace ==
+                # first technology then).
                 recorder=(rec if pricings[0].certified else None),
                 requests=fleet.logical,
                 finished=fleet.finished_logical,
@@ -512,34 +336,31 @@ def _fleet_grid_point(
                 offered_qps=cfg.arrival_rate_rps,
                 pages_spilled=fleet.pages_spilled(),
                 pages_allocated=fleet.pages_allocated(),
+                stats=fleet.stats,
             )
-            fm_by_tech = dict(zip(spec.technologies, fms))
-            shared_fleet = {
+            shared = {
                 tech: fleet.finalize(
                     rep, p.system,
-                    fault_stats=(fm_by_tech[tech].stats()
-                                 if fm_by_tech[tech] is not None else None))
-                for (tech, p), rep in zip(certified, reports)
+                    fault_stats=fm.stats() if fm is not None else None)
+                for (tech, p, fm), rep in zip(certified, reports)
             }
     timing["score_s"] += time.perf_counter() - t0
 
-    out = []
-    for tech, pricing in zip(spec.technologies, pricings):
-        if pricing.certified:
-            fr = shared_fleet[tech]
-            out.append(SweepRow(tech, cap, qps, True, fr.report, fleet=fr))
-        else:
-            # Congestion would have re-interleaved this technology's fleet:
-            # run its own exact fleet loop (off the registry build — the
-            # exact loop derates the base system itself).
-            with obs.span("fallback"):
-                _, fr = fleet_serving(
-                    build_system(tech, cap), nlp, cfg, spec.engine,
-                    spec.fleet, sim_config=sim_config,
-                    n_dram_channels=n_dram_channels,
-                    n_prefetch_channels=n_prefetch_channels,
-                    lowering=lowering, timing=timing,
-                    faults=faults,
-                )
-            out.append(SweepRow(tech, cap, qps, False, fr.report, fleet=fr))
-    return out
+    for tech in spec.technologies:
+        if tech in shared:
+            results.append((tech, True, shared[tech]))
+            continue
+        # Congestion would have stretched this technology's steps: run its
+        # own loop.  The shared loop already recorded this grid point's
+        # lifecycles, so the fallback records nothing.
+        with obs.span("fallback"):
+            results.append((tech, False, own_loop(tech)))
+    return _rows(spec, cap, qps, results)
+
+
+def _rows(spec: ServingGridSpec, cap: float, qps: float,
+          results: list) -> list[SweepRow]:
+    # A single accelerator's rows carry no fleet extras.
+    return [SweepRow(tech, cap, qps, shared, fr.report,
+                     fleet=None if spec.fleet.trivial else fr)
+            for tech, shared, fr in results]

@@ -8,9 +8,11 @@ import pathlib
 import numpy as np
 import pytest
 
+from _golden_digest import report_digest, report_lines, trace_digest
 from repro.core.memory_system import HybridMemorySystem, glb_array
 from repro.core.workload import NLP_TABLE_V
 from repro.dse.serving import ServingSLO, ServingSweepSpec, evaluate_serving_grid, slo_knee
+from repro.faults import FaultConfig
 from repro.serve import (
     FleetConfig,
     ServeEngineConfig,
@@ -56,27 +58,53 @@ def _trace_identical(a, b) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# The conservation law: R=1 fleet == single-accelerator closed loop, bitwise
+# The single-accelerator closed loop is the R=1 fleet, pinned to literals
 # ---------------------------------------------------------------------------
+
+# (faults, trace sha256, report sha256) of closed_loop_serving on _system(),
+# _gpt2(), _cfg(), _ecfg(); see tests/_golden_digest.py.
+_CLOSED_LOOP_PINS = {
+    "none": (
+        None,
+        "f16f8feedf293fa5afb7307531c1c2926bfab0e08c827826d2cf86e19e1dd7c1",
+        "8cbc0ff4468e83f6d08b001b980bba621f58a909334376b98053cae971082440",
+    ),
+    # Injects write retries and bank-offline remap windows.
+    "faulted": (
+        FaultConfig(seed=3, write_error_scale=50.0, bank_fault_scale=1e6),
+        "b851c68d3d01fb35adbad373cd102f9547c0dda4801e95c9a53056fade7be4a6",
+        "7123388829d04801e5a443cd78bacf2d0872138a354e74df1690a9e61c5638b0",
+    ),
+}
 
 
 def test_one_replica_fleet_bit_identical_to_closed_loop():
     system, spec = _system(), _gpt2()
     cfg, ecfg = _cfg(), _ecfg()
+    _, trace_pin, report_pin = _CLOSED_LOOP_PINS["none"]
     tr_ref, rep_ref = closed_loop_serving(system, spec, cfg, ecfg)
+    assert trace_digest(tr_ref) == trace_pin
+    assert report_digest(rep_ref) == report_pin, report_lines(rep_ref)
     tr_one, fr_one = fleet_serving(system, spec, cfg, ecfg, FleetConfig())
-    assert _trace_identical(tr_ref, tr_one)
-    for f in dataclasses.fields(rep_ref):
-        va, vb = getattr(rep_ref, f.name), getattr(fr_one.report, f.name)
-        if f.name == "sim":
-            assert dataclasses.astuple(va) == dataclasses.astuple(vb)
-        else:
-            assert va == vb, f.name
+    assert trace_digest(tr_one) == trace_pin
+    assert report_digest(fr_one.report) == report_pin
     assert fr_one.n_replicas == 1 and fr_one.n_replicas_peak == 1
     assert fr_one.mean_alive_replicas == 1.0
     # 1 chip: cost-per-token degenerates to area x energy/token.
     assert fr_one.cost_per_token == pytest.approx(
         system.glb.area_mm2 * fr_one.energy_per_token_j)
+
+
+def test_faulted_closed_loop_golden():
+    faults, trace_pin, report_pin = _CLOSED_LOOP_PINS["faulted"]
+    tr, rep = closed_loop_serving(_system(), _gpt2(), _cfg(), _ecfg(),
+                                  faults=faults)
+    stats = tr.meta["fault_stats"]
+    assert stats["retry_accesses"] > 0 and stats["banks_remapped"] > 0
+    # The trace meta records the caller's campaign unchanged.
+    assert tr.meta["faults"] == faults.to_dict()
+    assert trace_digest(tr) == trace_pin
+    assert report_digest(rep) == report_pin, report_lines(rep)
 
 
 def test_pre_fleet_scenario_json_runs_bit_identical():
@@ -259,7 +287,7 @@ def test_fleet_sweep_shared_matches_exact():
             assert getattr(rs.report, m) == getattr(re_.report, m), m
         # Whole-trace float reductions (energy -> cost) may differ in the
         # last ulp when two replicas step at the same timestamp (step-major
-        # vs class-major append order); see sweep._fleet_grid_point.
+        # vs class-major append order); see sweep._grid_point.
         assert rs.fleet.cost_per_token == pytest.approx(
             re_.fleet.cost_per_token, rel=1e-12)
         assert rs.fleet.n_replicas == re_.fleet.n_replicas == 3
