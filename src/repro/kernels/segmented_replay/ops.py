@@ -21,7 +21,7 @@ needs both rows sorted, as ``numpy.searchsorted`` in :mod:`.ref` needs its
 first: within a bank segment finish times are nondecreasing (a running max
 plus a nondecreasing local sum) and so are issue times (events are sorted
 by ``(resource, t_issue)``); the ``seg_id * big2`` offsets keep segments
-apart; and the neutral pad tail sorts above every real lane.
+apart; and the pad tail sorts above every real lane.
 
 Only order operations run on the device, and only on 32-bit integers.
 The host hands each keyed float64 array over as its raw IEEE bits (a
@@ -47,15 +47,20 @@ float order agree on every tie.  :func:`.ref._to_pair` is the numpy
 reference of the word map.
 
 To bound recompiles across a sweep (event counts differ per grid point),
-``replay_scan`` pads rows to the next power of two with a *neutral tail
-segment*: pad values chosen so the padded entries form their own trailing
-segment whose finish/issue values stay inside the real data's range — the
-big2 span, every real output, and every real queue depth are bit-identical
-to the unpadded computation (see ``_pad_neutral``).
+the device sees rows padded to the next power of two; the host does not.
+Every float pass of ``replay_scan`` runs on the ``(R, n)`` real lanes,
+operand for operand as in :mod:`.ref`, and each key is written straight
+into the real part of an ``(R, npad)`` int64 bit buffer whose tail holds
+``+inf``'s bits (:data:`PAD_KEY`).  Real keys are finite, so the tail
+sorts above every one of them.  That is all the padding needs: the
+running max never carries a pad back into real lanes, since the pads come
+last; the merge rank still sees sorted rows; and no pad ``a`` counts
+below a real ``q``, since ``side="left"`` counts only keys strictly
+below.  The pads' own results are cut off on the host before any decode.
 
-With ``repro.obs`` enabled, each host phase opens a span (``pad``,
-``encode``, ``decode``: float work, zero-copy views and the running
-max's word interleave) and each device
+With ``repro.obs`` enabled, each host phase opens a span (``pad``: the
+bit buffers and their tails; ``encode``, ``decode``: float work on the
+real lanes and the running max's word interleave) and each device
 program one ``device/cummax`` or ``device/search`` span, from the first
 transfer of its key bits to the numpy result in hand (transfer in, word
 map and kernel, transfer out); ``replay_scan`` counts the lanes it gives
@@ -302,28 +307,25 @@ def _floats(h: np.ndarray, l: np.ndarray) -> np.ndarray:
     return w.view(np.float64)[..., 0]
 
 
-def _device_cummax(x, scan, chunk, interpret) -> np.ndarray:
-    """Row-wise running max of float64 ``x`` on the device, shipped as bits.
+def _device_cummax(bits, scan, chunk, interpret):
+    """Row-wise running max of float64 values given as int64 IEEE ``bits``.
 
-    Bitwise ``np.maximum.accumulate``; blocks until the result is on the
-    host.  The device orders int32 pairs only: XLA's running max over int64
-    replay keys disagreed with numpy on a v5e, while the same max over int32
-    pairs agreed.
+    Bitwise ``np.maximum.accumulate``, returned as its IEEE high and low
+    int32 words; blocks until they are on the host.  The device orders
+    int32 pairs only: XLA's running max over int64 replay keys disagreed
+    with numpy on a v5e, while the same max over int32 pairs agreed.
     """
-    bits = _bits(x)
     with obs.span("device/cummax"), x64():
         h, l = (np.asarray(w) for w in _replay_cummax(
             jax.device_put(bits), scan=scan, chunk=chunk, interpret=interpret))
     obs.count("replay/bytes_to_device", bits.nbytes)
     obs.count("replay/bytes_from_device", h.nbytes + l.nbytes)
-    with obs.span("decode"):
-        return _floats(h, l)
+    return h, l
 
 
-def _device_search(a, q) -> np.ndarray:
-    """:func:`_replay_search` of float64 rows ``a`` and ``q``, shipped as
-    bits; blocks for the numpy result."""
-    a_bits, q_bits = _bits(a), _bits(q)
+def _device_search(a_bits, q_bits) -> np.ndarray:
+    """:func:`_replay_search` of float64 rows given as int64 IEEE bits;
+    blocks for the numpy result."""
     with obs.span("device/search"), x64():
         idx = np.asarray(_replay_search(jax.device_put(a_bits),
                                         jax.device_put(q_bits)))
@@ -342,7 +344,9 @@ def cummax(
     """Row-wise running max of a 2D array, bitwise ``np.maximum.accumulate``."""
     if interpret is None:
         interpret = _auto_interpret()
-    return _device_cummax(x, scan, chunk, interpret)
+    h, l = _device_cummax(_bits(x), scan, chunk, interpret)
+    with obs.span("decode"):
+        return _floats(h, l)
 
 
 def _next_pow2(n: int, floor: int = 4096) -> int:
@@ -352,32 +356,8 @@ def _next_pow2(n: int, floor: int = 4096) -> int:
     return p
 
 
-def _pad_neutral(v, seg_id, s_local, svc, t_s, npad):
-    """Pad ``(R, n)`` inputs to ``(R, npad)`` without perturbing real outputs.
-
-    The pad entries form one extra trailing segment per row (``seg_id`` one
-    past the row's last) with ``t = t_max`` (the row's latest issue time),
-    ``svc = s_local = 0`` and hence ``v = finish = t_max``.  Consequences,
-    all exact: the cummax never feeds pads back into real lanes (pads come
-    last); ``t_max`` lies inside ``[min(t), max(finish)]`` so the big2 span
-    is unchanged; and the pads' augmented finish times sort strictly above
-    every real entry, so real searchsorted insertion points are unchanged.
-    """
-    R, n = v.shape
-    pad = npad - n
-    t_max = t_s.max(axis=1, keepdims=True)
-    zeros = np.zeros((R, pad))
-
-    def cat(a, p):
-        return np.concatenate([a, p], axis=1)
-
-    return (
-        cat(v, np.broadcast_to(t_max, (R, pad))),
-        cat(seg_id, np.broadcast_to(seg_id[:, -1:] + 1, (R, pad))),
-        cat(s_local, zeros),
-        cat(svc, zeros),
-        cat(t_s, np.broadcast_to(t_max, (R, pad))),
-    )
+# The key of every pad lane: above every finite key, which every real one is.
+PAD_KEY = np.inf
 
 
 def replay_scan(
@@ -407,26 +387,32 @@ def replay_scan(
     npad = _next_pow2(n)
     obs.count("replay/lanes", R * n)
     obs.count("replay/lanes_padded", R * npad)
+    # The bits the device gets: the running max's keys in row 0, then the
+    # search's finish keys there and its issue keys in row 1.  The keys are
+    # computed straight into the real lanes, through float64 views.
+    bits = np.empty((2, R, npad), np.int64)
+    keys = bits[:, :, :n].view(np.float64)
     if npad != n:
         with obs.span("pad"):
-            v, seg_id, s_local, svc, t_s = _pad_neutral(
-                v, seg_id, s_local, svc, t_s, npad
-            )
+            bits[:, :, n:].view(np.float64)[...] = PAD_KEY
     with obs.span("encode"):
         off = seg_id * big[:, None]
-        key = v + off
-    key_max = _device_cummax(key, scan, chunk, interpret)
+        np.add(v, off, out=keys[0])
+    h, l = _device_cummax(bits[0], scan, chunk, interpret)
+    # Operand for operand as ``ref.replay_scan_np``, on the real lanes.
     with obs.span("decode"):
-        finish = s_local + (key_max - off)
+        finish = _floats(h[:, :n], l[:, :n])  # the running max
+        finish -= off
+        finish += s_local
         start = finish - svc
         wait = start - t_s
         fmax = np.maximum(finish.max(axis=1), t_s.max(axis=1))
         fmin = np.minimum(finish.min(axis=1), t_s.min(axis=1))
         big2 = (fmax - fmin) + 1.0
     with obs.span("encode"):
-        off2 = seg_id * big2[:, None]
-        a, q = finish + off2, t_s + off2
-    idx = _device_search(a, q)
+        off2 = np.multiply(seg_id, big2[:, None], out=off)
+        np.add(finish, off2, out=keys[0])
+        np.add(t_s, off2, out=keys[1])
+    idx = _device_search(bits[0], bits[1])
     with obs.span("decode"):
-        depth = np.arange(npad) - idx
-        return finish[:, :n], start[:, :n], wait[:, :n], depth[:, :n]
+        return finish, start, wait, np.arange(n) - idx[:, :n]

@@ -239,6 +239,8 @@ def test_sweep_spans_and_counters_leave_rows_bit_identical(backend,
     lanes = [n for name, n in counted if name == "replay/lanes"]
     padded = [n for name, n in counted if name == "replay/lanes_padded"]
     assert len(lanes) == len(padded) == len(grid.qps)
+    assert spans["sweep/score/replay/pad"]["calls"] == sum(
+        p != r for r, p in zip(lanes, padded))
     R = len(grid.technologies)
     for real, pad in zip(lanes, padded):
         assert pad >= real > 0 and pad % R == 0 and real % R == 0
@@ -276,6 +278,25 @@ def test_replay_byte_counters_follow_padded_lanes():
     assert padded > 0
     assert counters["replay/bytes_to_device"] == _BYTES_TO_DEVICE * padded
     assert counters["replay/bytes_from_device"] == _BYTES_FROM_DEVICE * padded
+
+
+def test_replay_pad_span_opens_once_per_call_that_pads():
+    """``pad`` opens once per ``replay_scan`` call whose rows are padded to a
+    power of two, and not at all where they already are one."""
+    pytest.importorskip("jax")
+    from repro.kernels.segmented_replay.ops import replay_scan
+
+    rng = np.random.default_rng(5)
+    obs.enable()
+    for n in (300, 4096, 5000):  # pads to 4096, none, pads to 8192
+        t = np.sort(rng.uniform(0, 1e4, (2, n)), axis=1)
+        seg = np.zeros((2, n))
+        svc = np.ones((2, n))
+        replay_scan(t, seg, svc, svc, t, np.ones(2))
+    spans = obs.snapshot()["spans"]
+    assert spans["pad"]["calls"] == 2
+    assert spans["encode"]["calls"] == spans["decode"]["calls"] == 6
+    assert spans["device/cummax"]["calls"] == 3
 
 
 def test_sweep_spans_land_on_the_profilers_host_line(tmp_path):

@@ -232,8 +232,14 @@ def test_cummax_matches_numpy(chunk):
 
 
 @needs_jax
+@pytest.mark.parametrize("R, n", [
+    (2, 5000),  # pads to 8192 (> the 4096 floor)
+    (2, 8192),  # a power of two above the floor: nothing is padded
+    (3, 300),   # below the floor: pads to 4096
+    (2, 1),     # one event a row
+])
 @pytest.mark.parametrize("t0", [0.0, -1e9])
-def test_replay_scan_padding_is_neutral(t0):
+def test_replay_scan_padding_is_neutral(t0, R, n):
     """Pow-2 padding must not perturb any real output, bitwise.
 
     ``t0 = -1e9`` puts every issue time, and so every key the device
@@ -242,7 +248,6 @@ def test_replay_scan_padding_is_neutral(t0):
     from repro.kernels.segmented_replay.ops import replay_scan
 
     rng = np.random.default_rng(11)
-    R, n = 2, 5000  # pads to 8192 (> the 4096 floor)
     t = t0 + np.sort(rng.uniform(0, 1e5, (R, n)), axis=1)
     svc = rng.uniform(1, 50, (R, n))
     seg_id = np.sort(rng.integers(0, 40, (R, n)), axis=1).astype(np.float64)
@@ -257,7 +262,7 @@ def test_replay_scan_padding_is_neutral(t0):
     for scan in ("lax", "pallas"):
         got = replay_scan(v, seg_id, s_local, svc, t, big, scan=scan)
         for name, a, b in zip(("finish", "start", "wait", "depth"), ref, got):
-            assert b.shape == (R, n)
+            assert b.shape == (R, n) and b.dtype == a.dtype
             np.testing.assert_array_equal(a, b, err_msg=f"{scan}/{name}")
 
 
@@ -288,15 +293,15 @@ def _word_case(case, R, n, rng):
         x = rng.normal(0.0, 1e11, (R, n))
         x[rng.random((R, n)) < 0.2] = np.inf
         x[rng.random((R, n)) < 0.2] = -np.inf
-    elif case == "pad_tail":  # keys of _pad_neutral's tail segment
-        from repro.kernels.segmented_replay.ops import _pad_neutral
+    elif case == "pad_tail":  # replay keys, then replay_scan's pad tail
+        from repro.kernels.segmented_replay.ops import PAD_KEY
 
         real = n - n // 3
         t = np.sort(rng.uniform(0, 1e5, (R, real)), axis=1)
         seg = np.sort(rng.integers(0, 20, (R, real)), axis=1).astype(np.float64)
-        svc = rng.uniform(1, 50, (R, real))
-        v, seg, _, _, _ = _pad_neutral(t - svc, seg, svc, svc, t, n)
-        x = v + seg * ((v.max(axis=1) - v.min(axis=1)) + 1.0)[:, None]
+        v = t - rng.uniform(1, 50, (R, real))
+        x = np.full((R, n), PAD_KEY)
+        x[:, :real] = v + seg * ((v.max(axis=1) - v.min(axis=1)) + 1.0)[:, None]
     else:
         raise AssertionError(case)
     bad = np.isnan(x) | ((x == 0) & np.signbit(x))
@@ -375,10 +380,12 @@ def _merge_rank_case(case, R, n, rng):
     if case == "negative_floats":
         return (rows(lambda s: -rng.exponential(1e6, s)),
                 rows(lambda s: -rng.exponential(1e6, s)))
-    if case == "pad_tail":  # _pad_neutral's tail: one key above every real lane
+    if case == "pad_tail":  # replay_scan's tail: PAD_KEY above every real lane
+        from repro.kernels.segmented_replay.ops import PAD_KEY
+
         a, q = rows(lambda s: rng.uniform(0, 1e5, s)), rows(lambda s: rng.uniform(0, 1e5, s))
         real = n - n // 3
-        a[:, real:] = q[:, real:] = 2e5
+        a[:, real:] = q[:, real:] = PAD_KEY
         return a, q
     raise AssertionError(case)
 
